@@ -71,11 +71,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.distances import match_vma
+from repro.core.distances import EXACT, match_vma
 from repro.kernels.beam_hop import beam_hop as _kernel_beam_hop
 from repro.kernels.beam_hop import merge_one
 from repro.kernels.gather_dist import gather_dist as _kernel_gather_dist
 from repro.kernels.lut_dist import lut_dist as _kernel_lut_dist
+from repro.kernels.row_gather import pad_table
 
 
 class BeamStats(NamedTuple):
@@ -106,7 +107,8 @@ def _sqdist_rows(query: jax.Array, rows: jax.Array) -> jax.Array:
     q = query.astype(jnp.float32)
     r = rows.astype(jnp.float32)
     return jnp.maximum(
-        jnp.sum(q * q) + jnp.sum(r * r, axis=-1) - 2.0 * (r @ q), 0.0)
+        jnp.sum(q * q) + jnp.sum(r * r, axis=-1)
+        - 2.0 * jnp.matmul(r, q, precision=EXACT), 0.0)
 
 
 def _select_frontier(pool_i, pool_d, pool_v):
@@ -341,7 +343,7 @@ def _batched_hop_setup(queries, db, neighbors, *, gather_dist,
     Returns ``(gd, body)``; ``gd`` also seeds the pool's entry distances.
     Under a quantized ``dist_backend`` the ``queries`` argument is only a
     placeholder for ``gd``'s signature (the LUT carries the per-query
-    operand).
+    operand). ``db``/``codes`` arrive through ``_kernel_tables``.
     """
     hop = resolve_hop_backend(hop_backend)
     if gather_dist is not None and hop == "fused":
@@ -351,11 +353,11 @@ def _batched_hop_setup(queries, db, neighbors, *, gather_dist,
             raise ValueError(
                 "hop_backend='fused' cannot honor a custom gather_dist "
                 "callable (distances are computed in-kernel)")
+    if dist_backend != "f32" and (codes is None or lut is None):
+        raise ValueError(
+            f"dist_backend={dist_backend!r} needs codes and lut "
+            f"(encode the db with a core.quant codec first)")
     if dist_backend != "f32":
-        if codes is None or lut is None:
-            raise ValueError(
-                f"dist_backend={dist_backend!r} needs codes and lut "
-                f"(encode the db with a core.quant codec first)")
         backend = resolve_gather_backend(gather_backend) or "jnp"
         gd = lambda q, db_, ids: _kernel_lut_dist(lut, codes, ids,
                                                   backend=backend)
@@ -387,6 +389,24 @@ def _batched_hop_setup(queries, db, neighbors, *, gather_dist,
     else:
         body = lambda s: _expand_batch(s, queries, db, neighbors, gd)
     return gd, body
+
+
+def _kernel_tables(db, codes, *, gather_dist, gather_backend, dist_backend):
+    """The row table the hops read, in the Pallas kernels' tile layout.
+
+    The kernels DMA whole (8, 128) tiles (``row_gather.pad_table``). Padded
+    by the kernels themselves, inside the hop loop, the table would be
+    copied on every hop (XLA sinks the pad into the loop body), so each
+    search pads it here, once, before its first hop: a no-op for an
+    aligned table or off the Pallas path.
+    """
+    if gather_dist is None and resolve_gather_backend(gather_backend) == \
+            "pallas":
+        if dist_backend == "f32":
+            db = pad_table(db)
+        else:
+            codes = pad_table(codes)
+    return db, codes
 
 
 def _seed_batched(queries, db, neighbors, entry_ids, ef, gd):
@@ -480,6 +500,9 @@ def _beam_search_batched(queries, db, neighbors, entry_ids, *, ef, k,
                          dist_backend="f32", codes=None, lut=None,
                          hop_backend=None, patience=None, eps=0.0,
                          with_stats=False):
+    db, codes = _kernel_tables(db, codes, gather_dist=gather_dist,
+                               gather_backend=gather_backend,
+                               dist_backend=dist_backend)
     gd, body = _batched_hop_setup(
         queries, db, neighbors, gather_dist=gather_dist,
         gather_backend=gather_backend, dist_backend=dist_backend,
@@ -614,6 +637,10 @@ def beam_search_compacted(queries: jax.Array, db: jax.Array,
 
     slice_kw = dict(gather_dist=gather_dist, gather_backend=gather_backend,
                     dist_backend=dist_backend, hop_backend=hop_backend)
+    # padded once for every slice of this search, not once per slice
+    db, codes = _kernel_tables(db, codes, gather_dist=gather_dist,
+                               gather_backend=gather_backend,
+                               dist_backend=dist_backend)
 
     b0 = bucket_for(nq, buckets)
     q_cur = pad_rows(jnp.asarray(queries), b0)

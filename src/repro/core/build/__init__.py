@@ -30,7 +30,7 @@ from repro.core.build.finish import (
 from repro.core.build.nn_descent import BuildStats, nn_descent
 from repro.core.build.pools import nnd_candidate_pools
 from repro.core.build.prune import (
-    RepruneFamily, alpha_prune, alpha_prune_mask, mark_dups,
+    RepruneFamily, alpha_prune, alpha_prune_mask,
     nsg_from_neighbors, pairwise_rows_sqdist, prune_in_chunks, reprune,
     reprune_family, reprune_nsg, rows_sqdist_in_chunks, sorted_adjacency,
     sorted_adjacency_chunk,
@@ -44,7 +44,7 @@ __all__ = [
     "AUTO_NND_MIN_N", "BuildStats", "DEFAULT_CHUNK", "FINISH_BACKENDS",
     "FinishStats", "HostOffloadStore", "RepruneFamily", "alpha_prune",
     "alpha_prune_mask", "build_knn", "chunk_spans", "derive_local",
-    "finish_nsg", "knn_graph_recall", "mark_dups", "nn_descent",
+    "finish_nsg", "knn_graph_recall", "nn_descent",
     "nnd_candidate_pools", "nsg_from_neighbors", "pairwise_rows_sqdist",
     "prune_in_chunks", "reachable_mask", "repair",
     "repair_connectivity_device", "repair_local", "reprune",

@@ -54,10 +54,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.build.prune import (
-    mark_dups, prune_in_chunks, rows_sqdist_in_chunks,
-)
+from repro.core.build.prune import prune_in_chunks, rows_sqdist_in_chunks
+from repro.core.distances import match_vma
 from repro.kernels.topk_merge import topk_pool
+from repro.kernels.topk_merge.ref import mark_dups
 
 FINISH_BACKENDS = ("host", "device", "auto")
 
@@ -236,8 +236,10 @@ def propagate_reach(nbrs: jax.Array, seed: jax.Array) -> jax.Array:
         _, changed, it = state
         return changed & (it <= n)
 
+    # carries typed after the inputs (uniformly varying under shard_map)
     reach, _, _ = jax.lax.while_loop(
-        cond, body, (seed, jnp.asarray(True), jnp.asarray(0)))
+        cond, body, (seed, match_vma(jnp.asarray(True), nbrs, seed),
+                     match_vma(jnp.asarray(0), nbrs, seed)))
     return reach
 
 

@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.distances import EXACT
 from repro.kernels.topk_merge import resolve_merge_backend, topk_merge
 
 
@@ -135,7 +136,8 @@ def _rp_block_join(key, data, norms, ids, dists, fresh, bsize, block,
         vecs = data[safe].astype(jnp.float32)
         nn = norms[safe]
         t = jnp.maximum(nn[:, None] + nn[None, :]
-                        - 2.0 * (vecs @ vecs.T), 0.0)
+                        - 2.0 * jnp.matmul(vecs, vecs.T, precision=EXACT),
+                        0.0)
         valid = ((g[:, None] >= 0) & (g[None, :] >= 0)
                  & (g[:, None] != g[None, :]))
         ci = jnp.where(valid, jnp.broadcast_to(g[None, :], t.shape), -1)
@@ -164,7 +166,7 @@ def _seed_dists_chunk(data, norms, rows, init_chunk):
     vecs = data[safe].astype(jnp.float32)
     q = data[rows].astype(jnp.float32)
     d = (norms[rows][:, None] + norms[safe]
-         - 2.0 * jnp.einsum("bkd,bd->bk", vecs, q))
+         - 2.0 * jnp.einsum("bkd,bd->bk", vecs, q, precision=EXACT))
     return (jnp.where(valid, init_chunk, -1),
             jnp.where(valid, jnp.maximum(d, 0.0), jnp.inf),
             jnp.sum(valid, dtype=jnp.int32))
@@ -265,7 +267,7 @@ def _round(key, data, norms, ids, dists, fresh, s_fwd, s_rev, u_slots,
         vb = data[jnp.maximum(cb, 0)].astype(jnp.float32)    # (B, Mc, D)
         t = (norms[jnp.maximum(ra, 0)][:, :, None]
              + norms[jnp.maximum(cb, 0)][:, None, :]
-             - 2.0 * jnp.einsum("bmd,bnd->bmn", va, vb))
+             - 2.0 * jnp.einsum("bmd,bnd->bmn", va, vb, precision=EXACT))
         t = jnp.maximum(t, 0.0)
         a_id = jnp.broadcast_to(ra[:, :, None], t.shape)
         b_id = jnp.broadcast_to(cb[:, None, :], t.shape)

@@ -30,6 +30,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.distances import match_vma
+
 
 @jax.jit
 def pairwise_rows_sqdist(q: jax.Array, data: jax.Array,
@@ -55,14 +57,6 @@ def rows_sqdist_in_chunks(data: jax.Array, ids: jax.Array,
     return jnp.concatenate(outs)
 
 
-@jax.jit
-def mark_dups(ids: jax.Array) -> jax.Array:
-    """True at positions holding a value already seen to the left."""
-    eq = ids[:, :, None] == ids[:, None, :]                    # (B, L, L)
-    tri = jnp.tril(jnp.ones(eq.shape[-2:], bool), k=-1)
-    return jnp.any(eq & tri[None], axis=-1) | (ids < 0)
-
-
 def _alpha_scan(data, node_ids, cand_ids, cand_dists, degree, alpha):
     """The greedy α-RNG occlusion scan, vmapped over a node block.
 
@@ -73,9 +67,13 @@ def _alpha_scan(data, node_ids, cand_ids, cand_dists, degree, alpha):
     L = cand_ids.shape[1]
 
     def prune_one(p, c_ids, c_d):
-        keep = jnp.full((degree,), -1, jnp.int32)
-        kept_vecs = jnp.zeros((degree, data.shape[1]), jnp.float32)
-        mask = jnp.zeros((L,), bool)
+        # under shard_map the fori carry must vary over the mesh axes like
+        # the loop body's outputs do: type the constants after the inputs
+        refs = (data, p, c_ids, c_d)
+        keep = match_vma(jnp.full((degree,), -1, jnp.int32), *refs)
+        kept_vecs = match_vma(
+            jnp.zeros((degree, data.shape[1]), jnp.float32), *refs)
+        mask = match_vma(jnp.zeros((L,), bool), *refs)
 
         def body(j, state):
             keep, kept_vecs, mask, cnt = state
@@ -95,7 +93,8 @@ def _alpha_scan(data, node_ids, cand_ids, cand_dists, degree, alpha):
             return keep, kept_vecs, mask, cnt + ok.astype(jnp.int32)
 
         keep, _, mask, _ = jax.lax.fori_loop(
-            0, L, body, (keep, kept_vecs, mask, 0))
+            0, L, body, (keep, kept_vecs, mask,
+                         match_vma(jnp.int32(0), *refs)))
         return keep, mask
 
     return jax.vmap(prune_one)(node_ids, cand_ids, cand_dists)

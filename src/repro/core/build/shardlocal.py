@@ -42,6 +42,7 @@ import jax.numpy as jnp
 
 from repro.core.build.finish import _choose_winners, propagate_reach
 from repro.core.build.prune import alpha_prune, pairwise_rows_sqdist
+from repro.core.distances import match_vma
 
 # Row-block size for the lax.map-streamed passes below: bounds every f32
 # temp at (BLK, R[, D]) whatever the shard size is.
@@ -143,7 +144,9 @@ def repair_local(data: jax.Array, nbrs: jax.Array, knn_ids: jax.Array,
     medoid = jnp.asarray(medoid, jnp.int32)
     rows = jnp.arange(n, dtype=jnp.int32)
     seed = jnp.zeros((n,), bool).at[medoid].set(True)
-    prot0 = jnp.zeros((n, r), bool)
+    # carries typed after the inputs (uniformly varying under shard_map)
+    refs = (data, nbrs, knn_ids, medoid, valid)
+    prot0 = match_vma(jnp.zeros((n, r), bool), *refs)
     reach0 = propagate_reach(nbrs, seed) & valid
 
     def cond(st):
@@ -170,8 +173,9 @@ def repair_local(data: jax.Array, nbrs: jax.Array, knn_ids: jax.Array,
         return nbrs, prot, reach, force, rounds + 1
 
     nbrs, _, _, _, rounds = jax.lax.while_loop(
-        cond, body, (nbrs, prot0, reach0, jnp.asarray(False),
-                     jnp.asarray(0)))
+        cond, body, (nbrs, prot0, reach0,
+                     match_vma(jnp.asarray(False), *refs),
+                     match_vma(jnp.asarray(0), *refs)))
     return nbrs, rounds
 
 

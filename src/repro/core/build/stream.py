@@ -14,8 +14,8 @@ composes out of:
 
   * **``HostOffloadStore``** — the chunked host-offload tier: keyed
     pytrees of arrays parked in host buffers (pinned-host device memory
-    when the backend exposes a ``pinned_host`` memory space, plain numpy
-    otherwise), with one-deep *prefetch*: ``prefetch(key)`` starts the
+    when the accelerator exposes a ``pinned_host`` memory space, plain
+    numpy on CPU), with one-deep *prefetch*: ``prefetch(key)`` starts the
     async ``device_put`` of the NEXT chunk while the CURRENT chunk's
     device work is still dispatched, so on an async backend the H2D
     transfer overlaps compute. ``fetch(key)`` consumes the staged copy
@@ -43,16 +43,14 @@ def chunk_spans(n: int, chunk: Optional[int] = None
 
 
 def pinned_host_sharding():
-    """A pinned-host placement target, or None when the backend has no
-    distinct host memory space (CPU: arrays are host-resident anyway)."""
-    try:
-        dev = jax.devices()[0]
-        if "pinned_host" in getattr(dev, "memory_kinds", ()):
-            return jax.sharding.SingleDeviceSharding(
-                dev, memory_kind="pinned_host")
-    except Exception:
-        pass
-    return None
+    """A pinned-host placement target, or None on CPU (where arrays are
+    host-resident anyway) and on backends without that memory space."""
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    if "pinned_host" not in {m.kind for m in dev.addressable_memories()}:
+        return None
+    return jax.sharding.SingleDeviceSharding(dev, memory_kind="pinned_host")
 
 
 def _to_host(x):
@@ -61,6 +59,11 @@ def _to_host(x):
     if pin is not None:
         return jax.device_put(x, pin)
     return np.asarray(x)
+
+
+def _to_device(x):
+    """One host buffer -> the default device's own memory."""
+    return jax.device_put(x, jax.devices()[0])
 
 
 class HostOffloadStore:
@@ -94,14 +97,13 @@ class HostOffloadStore:
         """Start the async device transfer of ``key``'s tree (no-op when
         unknown or already staged)."""
         if key in self._host and key not in self._staged:
-            self._staged[key] = jax.tree.map(jax.device_put,
-                                             self._host[key])
+            self._staged[key] = jax.tree.map(_to_device, self._host[key])
 
     def fetch(self, key):
         """Device-resident tree for ``key`` (consumes the staged copy)."""
         tree = self._staged.pop(key, None)
         if tree is None:
-            tree = jax.tree.map(jax.device_put, self._host[key])
+            tree = jax.tree.map(_to_device, self._host[key])
         return tree
 
     def peek_host(self, key):

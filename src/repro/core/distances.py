@@ -31,6 +31,11 @@ def match_vma(x: jax.Array, *refs: jax.Array) -> jax.Array:
     return x + z.astype(x.dtype)
 
 
+# Distance matmuls rank neighbors, so they run with f32 products: a TPU's
+# default precision rounds f32 matmul operands to bf16. CPU ignores it.
+EXACT = jax.lax.Precision.HIGHEST
+
+
 def pairwise_sqdist(q: jax.Array, x: jax.Array) -> jax.Array:
     """Squared L2 distances. q: (Q, D), x: (N, D) -> (Q, N)."""
     # accumulate in f32 even for bf16 inputs: the -2qx term cancels
@@ -39,7 +44,7 @@ def pairwise_sqdist(q: jax.Array, x: jax.Array) -> jax.Array:
     x32 = x.astype(jnp.float32)
     qn = jnp.sum(q32 * q32, axis=-1, keepdims=True)          # (Q, 1)
     xn = jnp.sum(x32 * x32, axis=-1)                          # (N,)
-    d = qn + xn[None, :] - 2.0 * (q32 @ x32.T)
+    d = qn + xn[None, :] - 2.0 * jnp.matmul(q32, x32.T, precision=EXACT)
     return jnp.maximum(d, 0.0)
 
 
@@ -63,6 +68,7 @@ def l2_topk(queries: jax.Array, database: jax.Array, k: int,
     n, d = database.shape
     q = queries.shape[0]
     k = min(k, n)
+    chunk = min(chunk, n)       # a small database is one unpadded block
     n_chunks = -(-n // chunk)
     pad = n_chunks * chunk - n
     db = jnp.pad(database, ((0, pad), (0, 0)))

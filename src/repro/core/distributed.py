@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import copy
 import functools
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, replace as dc_replace
 from typing import Optional
 
@@ -52,9 +53,7 @@ from repro.core.build.stream import HostOffloadStore
 from repro.core.distances import l2_topk
 from repro.core.index_api import build_index
 from repro.core.pipeline import IndexParams, TunedGraphIndex
-from repro.distributed.sharding import (
-    row_sharded_from_blocks, shard_map,
-)
+from repro.distributed.sharding import row_sharded_from_blocks
 
 
 def shard_bounds(n: int, s: int) -> np.ndarray:
@@ -129,7 +128,7 @@ def make_sharded_l2_topk(mesh: Mesh, k: int, chunk: int = 16384):
         d, i = l2_topk(q, db_local, k, chunk=chunk)
         return d, jnp.where(i >= 0, i + offset, -1)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(batch, None), P("model", None), P("model")),
         out_specs=(P(batch, "model"), P(batch, "model")))
@@ -220,7 +219,7 @@ def make_search_step(mesh: Mesh, *, ef: int, k: int, max_iters: int = 0,
         _local_beam, ef=ef, k=k, max_iters=max_iters, mode=mode,
         prenorm=flags.ANN_PRENORM)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local_search, mesh=mesh,
         in_specs=(P(batch, None), P("model", None), P("model", None),
                   P("model"), P("model", None), P("model"), P("model")),
@@ -305,12 +304,21 @@ class ShardedIndex:
         n, d0 = data.shape
         s = self.n_shards
         bounds = shard_bounds(n, s)
-        subs = []
-        for i in range(s):
-            sub = TunedGraphIndex(p).fit(
-                jnp.asarray(data[int(bounds[i]):int(bounds[i + 1])]),
-                jax.random.fold_in(key, i))
-            subs.append(sub)
+        # shard i builds on (and keeps its blocks on) the first device of
+        # its `model` column, so no device holds more than its own shard;
+        # the shard builds are independent, so they run concurrently, one
+        # host thread per device
+        home = np.moveaxis(
+            self.mesh.devices, self.mesh.axis_names.index("model"), 0
+        ).reshape(s, -1)[:, 0]
+
+        def fit_shard(i):
+            rows = data[int(bounds[i]):int(bounds[i + 1])]
+            return TunedGraphIndex(p).fit(jax.device_put(rows, home[i]),
+                                          jax.random.fold_in(key, i))
+
+        with ThreadPoolExecutor(max_workers=s) as pool:
+            subs = list(pool.map(fit_shard, range(s)))
         self.subs = subs
         self.n_structural_builds += s
         m = max(sub.ntotal for sub in subs)
@@ -331,7 +339,9 @@ class ShardedIndex:
         from repro import flags
         base_dt = jnp.bfloat16 if flags.ANN_BF16_BASE else jnp.float32
         blocks = [_shard_blocks(sub, m=m, c=c, offset=int(bounds[i]),
-                                mean=mean, comp=comp, base_dt=base_dt)
+                                mean=jax.device_put(mean, home[i]),
+                                comp=jax.device_put(comp, home[i]),
+                                base_dt=base_dt)
                   for i, sub in enumerate(subs)]
 
         def rows(field, *trailing):
@@ -344,8 +354,8 @@ class ShardedIndex:
             global_ids=rows("global_ids"),
             centroids=rows("centroids", None),
             members=rows("members"),
-            pca_mean=jax.device_put(mean),
-            pca_comp=jax.device_put(comp),
+            pca_mean=jax.device_put(mean, NamedSharding(self.mesh, P())),
+            pca_comp=jax.device_put(comp, NamedSharding(self.mesh, P())),
             base_norms=rows("base_norms"),
         )
         self.struct_neighbors = self.arrays.neighbors
@@ -377,7 +387,7 @@ class ShardedIndex:
             return derive_local(base, snbrs, knn, med[0], gids >= 0,
                                 alpha=a[0], degree=r_out)
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P("model", None), P("model", None), P("model", None),
                       P("model"), P("model"), P()),

@@ -12,6 +12,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.distances import EXACT
+
 
 @dataclass(frozen=True)
 class PCA:
@@ -24,10 +26,10 @@ class PCA:
         return self.components.shape[1]
 
     def transform(self, x: jax.Array) -> jax.Array:
-        return (x - self.mean) @ self.components
+        return jnp.matmul(x - self.mean, self.components, precision=EXACT)
 
     def inverse_transform(self, z: jax.Array) -> jax.Array:
-        return z @ self.components.T + self.mean
+        return jnp.matmul(z, self.components.T, precision=EXACT) + self.mean
 
 
 @functools.partial(jax.jit, static_argnames=("dim",))
@@ -35,7 +37,7 @@ def _fit(x: jax.Array, dim: int):
     x32 = x.astype(jnp.float32)
     mean = jnp.mean(x32, axis=0)
     xc = x32 - mean
-    cov = (xc.T @ xc) / (x.shape[0] - 1)
+    cov = jnp.matmul(xc.T, xc, precision=EXACT) / (x.shape[0] - 1)
     evals, evecs = jnp.linalg.eigh(cov)          # ascending
     evals = evals[::-1]
     evecs = evecs[:, ::-1]

@@ -8,6 +8,8 @@ from __future__ import annotations
 
 import copy
 import functools
+import logging
+import threading
 import time
 from dataclasses import asdict, dataclass, replace
 from typing import Optional
@@ -18,9 +20,7 @@ import numpy as np
 
 from repro.configs.base import ANNConfig
 from repro.core import antihub as antihub_mod
-from repro.core.beam_search import (
-    beam_search, beam_search_compacted, resolve_gather_backend,
-)
+from repro.core.beam_search import beam_search, beam_search_compacted
 from repro.core.build import build_knn, reprune_nsg, resolve_backend
 from repro.core.build.nn_descent import nn_descent
 from repro.core.entry_points import EntryPointSelector, fit_entry_points
@@ -29,11 +29,14 @@ from repro.core.pca import PCA, fit_pca
 from repro.core.quant import make_codec
 from repro.kernels.gather_dist import gather_dist as _gather_dist
 
+log = logging.getLogger(__name__)
+
 # Module-level structural-build counter: every TunedGraphIndex.fit (a real
 # graph build: pools + prune + interconnect) increments it. Rebuild-free
 # derivations (reprune, with_graph, the tuner's grid lookups, sharded
 # reprune) do NOT — tests assert sweeps leave it untouched.
 _N_STRUCTURAL_BUILDS = 0
+_BUILDS_LOCK = threading.Lock()     # sharded fits build shards in threads
 
 # NN-Descent refinement rounds for the antihub-subset reuse path: the
 # filtered full-data table is already a good approximation, so a couple of
@@ -129,6 +132,7 @@ class TunedGraphIndex:
         self.eps: Optional[EntryPointSelector] = None
         self.build_seconds: float = 0.0
         self.knn_seconds: float = 0.0                # kNN-graph phase
+        self.stage_seconds: dict = {}                # fit wall-clock/stage
         self.build_stats = None                      # NSGBuildStats of fit
         self.input_dim: int = 0
         self.knn_ids: Optional[jax.Array] = None     # build-time kNN table
@@ -147,6 +151,10 @@ class TunedGraphIndex:
         database, reused for the AntiHub k-occurrence pass (the tuner
         computes them once and threads them through every trial instead of
         paying an O(N^2) pass per structural build).
+
+        ``stage_seconds`` records each stage's wall-clock, timed to ready:
+        antihub, pca, knn, nsg (split further in ``build_stats``),
+        entry_points and, for a quantized index, quantize.
         """
         global _N_STRUCTURAL_BUILDS
         t0 = time.perf_counter()
@@ -154,6 +162,17 @@ class TunedGraphIndex:
         p = self.params
         n, d0 = data.shape
         self.input_dim = d0
+
+        stages = self.stage_seconds = {}
+        t_stage = time.perf_counter()
+
+        def lap(name, *ready):
+            nonlocal t_stage
+            jax.block_until_ready(ready)
+            now = time.perf_counter()
+            stages[name] = now - t_stage
+            t_stage = now
+            log.info("fit: %s %.1fs", name, stages[name])
 
         ah_ids = antihub_knn_ids
         if p.antihub_keep < 1.0:
@@ -166,6 +185,7 @@ class TunedGraphIndex:
         else:
             self.kept_idx = jnp.arange(n, dtype=jnp.int32)
             sub = data
+        lap("antihub", sub)
 
         if p.pca_dim < d0:
             self.pca = fit_pca(sub, p.pca_dim)
@@ -174,6 +194,7 @@ class TunedGraphIndex:
             self.pca = None
             base = sub
         self.base = base
+        lap("pca", base)
 
         t_knn = time.perf_counter()
         resolved_knn = resolve_backend(p.knn_backend, base.shape[0])
@@ -200,7 +221,7 @@ class TunedGraphIndex:
                 base, p.build_knn_k, backend=p.knn_backend,
                 key=jax.random.fold_in(key, 23))
         self.knn_ids = knn_ids
-        jax.block_until_ready(knn_ids)
+        lap("knn", knn_ids)
         self.knn_seconds = time.perf_counter() - t_knn
 
         pools = p.pools_backend
@@ -216,11 +237,15 @@ class TunedGraphIndex:
             n_candidates=p.build_candidates,
             alpha=p.alpha, pools_backend=pools, knn_dists=knn_dists,
             finish_backend=p.finish_backend, with_stats=True)
+        lap("nsg", self.graph.neighbors)
         self.eps = fit_entry_points(key, base, p.ep_clusters)
+        lap("entry_points", self.eps.centroids)
         if p.dist_backend != "f32":
             self.quantize(key=jax.random.fold_in(key, 29))
+            lap("quantize", self.codes)
         self.build_seconds = time.perf_counter() - t0
-        _N_STRUCTURAL_BUILDS += 1
+        with _BUILDS_LOCK:
+            _N_STRUCTURAL_BUILDS += 1
         return self
 
     def quantize(self, dist_backend: Optional[str] = None,
@@ -508,12 +533,12 @@ def _exact_rerank(queries: jax.Array, base: jax.Array, ids: jax.Array,
                   k: int):
     """Exact f32 squared-L2 rescoring of the (Q, R') beam survivors -> top-k.
 
-    One gather_dist block over the survivor ids (Pallas on TPU, jnp ref
-    elsewhere — the same dispatch the f32 hop uses), then a top-k re-sort.
-    Padded ids (-1) carry +inf and sort last.
+    One gather_dist block over the survivor ids, then a top-k re-sort.
+    Padded ids (-1) carry +inf and sort last. The jnp form gathers just
+    the Q x R' rows; the Pallas kernel would first copy the whole table
+    into its tile layout, and gives the same bits.
     """
-    backend = resolve_gather_backend(None) or "jnp"
-    d = _gather_dist(queries, base, ids, backend=backend)
+    d = _gather_dist(queries, base, ids, backend="jnp")
     neg, pos = jax.lax.top_k(-d, k)
     return -neg, jnp.take_along_axis(ids, pos, axis=1)
 

@@ -20,17 +20,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                      # JAX >= 0.6: public top-level API
-    shard_map = jax.shard_map
-except AttributeError:                    # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, **kwargs):
-        # the legacy replication checker has no rule for while_loop (our
-        # beam-search hot path); the modern checker doesn't need disabling
-        kwargs.setdefault("check_rep", False)
-        return _shard_map_legacy(f, **kwargs)
-
 # ---------------------------------------------------------------------------
 # ambient mesh for in-model sharding constraints
 # ---------------------------------------------------------------------------

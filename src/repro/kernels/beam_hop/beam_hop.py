@@ -3,26 +3,28 @@
 One beam hop used to be three device round trips — gather the (Q, R)
 neighbor ids, score them (``kernels/gather_dist`` or ``kernels/lut_dist``),
 then merge into the (Q, ef) pool — with the candidate id and distance
-blocks spilled to HBM between stages. This kernel is the ROADMAP fusion:
-the per-query selected node id is scalar-prefetched, its graph row is
-DMA'd by a BlockSpec index_map, the R candidate rows (f32 vectors or uint8
-codes, picked by a static ``dist_backend``) are streamed HBM->VMEM with a
-double-buffered ``make_async_copy`` gather, distances accumulate in
-registers, and a bitonic dedup-merge against the resident pool writes the
-updated (ids, dists, visited) state — the (Q, R) block never touches HBM.
+blocks spilled to HBM between stages. Here the (Q, R) graph rows of the
+selected nodes are one XLA gather (R ids per query), and everything after
+it is one launch: each grid step takes ``TB`` queries, streams their R
+candidate rows (f32 vectors or uint8 codes, picked by a static
+``dist_backend``) HBM->VMEM (``row_gather.fetch_rows``), scores them in
+VMEM, and merges them into the resident pool with a bitonic dedup-merge —
+the (Q, R) distance block never touches HBM.
 
 Bit-exactness with ``ref.py`` (and therefore with the staged path) is by
 construction:
 
-  * f32 distances use the diff-square form of ``kernels/gather_dist``
-    (sum((q - x)^2) over a (1, D) block); PQ/int8 use ``kernels/lut_dist``'s
-    one-hot select + left-to-right accumulation over M;
+  * f32 distances use the diff-square form of ``kernels/gather_dist``;
+    PQ/int8 use ``kernels/lut_dist``'s one-hot select + left-to-right
+    accumulation over M (the scores are the same ``row_gather`` code);
+  * a candidate is a duplicate when any pool lane holds its id, found by
+    rotating the (pool | candidates) id row past itself;
   * the merge sorts lanes by the lexicographic (distance, input position)
     key, which reproduces the reference's single *stable* argsort exactly —
     including +inf padding ties — via the strict-comparator bitonic network
     shared with ``kernels/topk_merge``.
 
-Grid: (Q,) — one query's full hop per step; queries pipeline across steps.
+Grid: (ceil(Q / TB),).
 """
 from __future__ import annotations
 
@@ -33,8 +35,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-from repro.kernels.bitonic import bitonic_by, pow2_at_least
+from repro.kernels.bitonic import bitonic_by, network_width
+from repro.kernels.row_gather import (
+    TB, compiler_params, fetch_rows, l2_scores, lut_scores, lut_scratch,
+    pad_block_rows, pad_table, row_scratch,
+)
 
 
 def _stable_gt(self_t, part_t):
@@ -44,73 +49,49 @@ def _stable_gt(self_t, part_t):
     return (sd > pd) | ((sd == pd) & (sp > pp))
 
 
-def _beam_hop_kernel(sel_ref, nbr_ref, pi_ref, pd_ref, pv_ref, q_ref,
+def _beam_hop_kernel(ids_ref, cand_ref, pi_ref, pd_ref, pv_ref, q_ref,
                      tab_ref, opi_ref, opd_ref, opv_ref, stats_ref,
-                     rows, dists, sem, *, dist_backend: str, r: int,
-                     ef: int, pad: int):
-    i = pl.program_id(0)
-    active = sel_ref[i] >= 0
-    nbr = nbr_ref[0, :]                           # graph row of sel (clamped)
-    valid = (nbr >= 0) & active                   # (R,)
-    safe = jnp.where(valid, nbr, 0)
+                     tiles, rows, sem, *lut_scr, dist_backend: str,
+                     width: int):
+    fetch_rows(ids_ref, tab_ref, tiles, rows, sem)
+    if dist_backend == "f32":
+        nd = l2_scores(rows, q_ref[...])
+    else:
+        nd = lut_scores(rows, q_ref, *lut_scr)
 
-    def start(slot, j):
-        pltpu.make_async_copy(tab_ref.at[safe[j]], rows.at[slot],
-                              sem.at[slot]).start()
-
-    start(0, 0)
-
-    def body(j, carry):
-        slot = j % 2
-
-        @pl.when(j + 1 < r)
-        def _():
-            start((j + 1) % 2, j + 1)
-
-        pltpu.make_async_copy(tab_ref.at[safe[j]], rows.at[slot],
-                              sem.at[slot]).wait()
-        row = rows[slot]
-        if dist_backend == "f32":
-            q = q_ref[...].astype(jnp.float32)            # (1, D)
-            x = row[None, :].astype(jnp.float32)          # (1, D)
-            diff = q - x
-            dists[0, j] = jnp.sum(diff * diff)
-        else:
-            m, c = q_ref.shape[1], q_ref.shape[2]
-            code = row.reshape(m, 1).astype(jnp.int32)    # (M, 1)
-            iota = jax.lax.broadcasted_iota(jnp.int32, (m, c), 1)
-            sel_v = jnp.where(iota == code, q_ref[0], 0.0)
-            per_m = jnp.sum(sel_v, axis=1)
-            acc = per_m[0]
-            for mm in range(1, m):
-                acc = acc + per_m[mm]
-            dists[0, j] = acc
-        return carry
-
-    jax.lax.fori_loop(0, r, body, 0)
-
-    nd = jnp.where(valid, dists[0, :], jnp.inf)
-    cand_i = jnp.where(valid, safe, -1)
-    dup = jnp.any(cand_i[:, None] == pi_ref[0][None, :], axis=1)
-    n_dup = jnp.sum(dup & (cand_i >= 0), dtype=jnp.int32)
-    bad = dup | (cand_i < 0)
-    cand_i = jnp.where(bad, -1, cand_i)
-    nd = jnp.where(bad, jnp.inf, nd)
-
+    tb, ef = pi_ref.shape
+    r = cand_ref.shape[1]
+    pad = width - ef - r
+    cand = cand_ref[...]                                  # -1 = invalid
     ids = jnp.concatenate(
-        [pi_ref[0], cand_i, jnp.full((pad,), -1, jnp.int32)])[None, :]
+        [pi_ref[...], cand, jnp.full((tb, pad), -1, jnp.int32)], axis=1)
     ds = jnp.concatenate(
-        [pd_ref[0], nd, jnp.full((pad,), jnp.inf, jnp.float32)])[None, :]
+        [pd_ref[...], jnp.where(cand >= 0, nd, jnp.inf),
+         jnp.full((tb, pad), jnp.inf, jnp.float32)], axis=1)
     vis = jnp.concatenate(
-        [pv_ref[0], jnp.zeros((r + pad,), bool)])[None, :]
-    pos = jax.lax.broadcasted_iota(jnp.int32, ids.shape, 1)
-    ds, pos, ids, vis = bitonic_by((ds, pos, ids, vis), _stable_gt,
-                                    ids.shape[1])
+        [pv_ref[...], jnp.zeros((tb, r + pad), jnp.int32)], axis=1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, ids.shape, 1)
+    is_cand = (lane >= ef) & (lane < ef + r)
+
+    # rotating by s pairs lane i with lane i - s: over s = 1..ef+r-1 every
+    # candidate lane meets every pool lane once
+    dup = jnp.zeros(ids.shape, bool)
+    for s in range(1, ef + r):
+        from_pool = (lane >= s) & (lane < s + ef)
+        dup = dup | ((ids == pltpu.roll(ids, s, 1)) & from_pool)
+    dup = dup & is_cand
+    live = is_cand & (ids >= 0)
+    gathered = jnp.sum(live.astype(jnp.int32), axis=1, keepdims=True)
+    n_dup = jnp.sum((dup & live).astype(jnp.int32), axis=1, keepdims=True)
+    bad = is_cand & (dup | (ids < 0))
+    ids = jnp.where(bad, -1, ids)
+    ds = jnp.where(bad, jnp.inf, ds)
+
+    ds, _, ids, vis = bitonic_by((ds, lane, ids, vis), _stable_gt, width)
     opi_ref[...] = ids[:, :ef]
     opd_ref[...] = ds[:, :ef]
     opv_ref[...] = vis[:, :ef]
-    stats_ref[0, 0] = jnp.sum(valid, dtype=jnp.int32)
-    stats_ref[0, 1] = n_dup
+    stats_ref[...] = jnp.concatenate([gathered, n_dup], axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("dist_backend", "interpret"))
@@ -121,57 +102,54 @@ def beam_hop_pallas(sel: jax.Array, neighbors: jax.Array, pool_i: jax.Array,
                     interpret: bool = True):
     """One fused hop over all Q lanes; see ``ref.beam_hop_ref`` for shapes.
 
-    ``table`` ((N, D) f32 db or (N, M) uint8 codes) stays in ANY memory
-    space; the kernel DMAs exactly the R needed rows per query. Inactive
-    lanes (sel < 0) index row 0 for the graph-row prefetch and mask every
-    candidate, so their pool state passes through unchanged (up to the
-    already-applied visited mark).
+    ``table`` ((N, D) f32 db or (N, M) uint8 codes, optionally pre-padded
+    by ``row_gather.pad_table``) stays in HBM; the kernel DMAs the tiles
+    holding the R needed rows per query. Inactive lanes (sel < 0) mask
+    every candidate, so their pool state passes through unchanged (up to
+    the already-applied visited mark).
     """
     nq, ef = pool_i.shape
     r = neighbors.shape[1]
-    pad = pow2_at_least(max(ef + r, 2)) - (ef + r)
+    width = network_width(ef + r)
+    nbr = neighbors[jnp.maximum(sel, 0)]
+    cand = pad_block_rows(
+        jnp.where((nbr >= 0) & (sel >= 0)[:, None], nbr, -1), -1)
+    table = pad_table(table)
+    pool_spec = pl.BlockSpec((TB, ef), lambda i: (i, 0))
     if dist_backend == "f32":
-        q_spec = pl.BlockSpec((1, q_or_lut.shape[1]),
-                              lambda i, s: (i, 0))
+        q_spec = pl.BlockSpec((TB, q_or_lut.shape[1]), lambda i: (i, 0))
+        scratch = row_scratch(r, table, quantized=False)
     else:
-        q_spec = pl.BlockSpec((1,) + q_or_lut.shape[1:],
-                              lambda i, s: (i, 0, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nq,),
-        in_specs=[
-            pl.BlockSpec((1, r), lambda i, s: (jnp.maximum(s[i], 0), 0)),
-            pl.BlockSpec((1, ef), lambda i, s: (i, 0)),
-            pl.BlockSpec((1, ef), lambda i, s: (i, 0)),
-            pl.BlockSpec((1, ef), lambda i, s: (i, 0)),
-            q_spec,
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, ef), lambda i, s: (i, 0)),
-            pl.BlockSpec((1, ef), lambda i, s: (i, 0)),
-            pl.BlockSpec((1, ef), lambda i, s: (i, 0)),
-            pl.BlockSpec((1, 2), lambda i, s: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, table.shape[1]), table.dtype),
-            pltpu.VMEM((1, r), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
+        q_spec = pl.BlockSpec((TB,) + q_or_lut.shape[1:],
+                              lambda i: (i, 0, 0))
+        scratch = (row_scratch(r, table, quantized=True)
+                   + lut_scratch(r, q_or_lut.shape[1]))
+    nqp = cand.shape[0]
     kernel = functools.partial(_beam_hop_kernel, dist_backend=dist_backend,
-                               r=r, ef=ef, pad=pad)
-    return pl.pallas_call(
+                               width=width)
+    opi, opd, opv, stats = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, ef), jnp.int32),
-            jax.ShapeDtypeStruct((nq, ef), jnp.float32),
-            jax.ShapeDtypeStruct((nq, ef), jnp.bool_),
-            jax.ShapeDtypeStruct((nq, 2), jnp.int32),
+        grid=(nqp // TB,),
+        in_specs=[
+            pl.BlockSpec((TB, r), lambda i: (i, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((TB, r), lambda i: (i, 0)),
+            pool_spec, pool_spec, pool_spec,
+            q_spec,
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel",)),
+        out_specs=[pool_spec, pool_spec, pool_spec,
+                   pl.BlockSpec((TB, 2), lambda i: (i, 0))],
+        out_shape=[
+            jax.ShapeDtypeStruct((nqp, ef), jnp.int32),
+            jax.ShapeDtypeStruct((nqp, ef), jnp.float32),
+            jax.ShapeDtypeStruct((nqp, ef), jnp.int32),
+            jax.ShapeDtypeStruct((nqp, 2), jnp.int32),
+        ],
+        scratch_shapes=scratch,
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
-    )(sel, neighbors, pool_i, pool_d, pool_v, q_or_lut, table)
+    )(cand, cand, pad_block_rows(pool_i, -1), pad_block_rows(pool_d, jnp.inf),
+      pad_block_rows(pool_v.astype(jnp.int32), 1),
+      pad_block_rows(q_or_lut, 0), table)
+    return opi[:nq], opd[:nq], opv[:nq] != 0, stats[:nq]

@@ -3,8 +3,15 @@
 `lax.sort` does not lower inside Pallas TPU kernels, so every in-kernel
 sort (``topk_merge``'s dedup-top-k, ``beam_hop``'s pool merge) is a bitonic
 network over VMEM-resident lane blocks. The compare-exchange partner
-``i XOR j`` (j a power of two) is a reshape-flip — no gathers, only
-reshapes, selects and iotas, all of which lower on TPU.
+``i XOR j`` (j a power of two) is lane ``i + j`` where bit j of i is clear
+and lane ``i - j`` where it is set: two lane rotations (``pltpu.roll``, the
+TPU's native rotate) and a select on ``lane & j``. Mosaic lowers rotates,
+selects and iotas; it does not lower ``rev``, so a reshape-flip partner
+fails to compile on TPU.
+
+Rotations work on any lane width, but the networks here always run on a
+width that is a power of two and at least one full 128-lane vreg
+(``network_width``).
 
 This module has no intra-repo imports on purpose: kernel packages can pull
 it in without touching ``core`` (whose import graph reaches back into the
@@ -14,13 +21,17 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
 
 
 def xor_partner(x, j):
-    """Lanes i and i^j exchanged (j a power of two) via reshape + flip."""
-    b, m = x.shape
-    y = x.reshape(b, m // (2 * j), 2, j)
-    return jnp.flip(y, axis=2).reshape(b, m)
+    """Lanes i and i^j exchanged along axis 1 (j a power of two)."""
+    m = x.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane & j) == 0, pltpu.roll(x, m - j, 1),
+                     pltpu.roll(x, j, 1))
 
 
 def bitonic_by(arrays, gt_fn, m):
@@ -37,10 +48,13 @@ def bitonic_by(arrays, gt_fn, m):
         while j >= 1:
             partners = tuple(xor_partner(a, j) for a in arrays)
             gt_sp = gt_fn(arrays, partners)        # self > partner
-            gt_ps = xor_partner(gt_sp, j)          # partner-side verdict
+            # partner-side verdict, moved to this lane
+            gt_ps = xor_partner(gt_sp.astype(jnp.int32), j) != 0
             lo = (lane & j) == 0                   # lane is the pair's low i
             asc = (lane & ksz) == 0                # ascending sub-sequence
-            take = jnp.where(lo == asc, gt_sp, gt_ps)
+            # a select between bool vectors does not lower: spell it out
+            own = lo == asc
+            take = (own & gt_sp) | (~own & gt_ps)
             arrays = tuple(jnp.where(take, p, a)
                            for a, p in zip(arrays, partners))
             j //= 2
@@ -53,3 +67,8 @@ def pow2_at_least(x: int) -> int:
     while p < x:
         p *= 2
     return p
+
+
+def network_width(x: int) -> int:
+    """Lane width of a network over ``x`` entries: a power of two, >= 128."""
+    return max(LANES, pow2_at_least(x))

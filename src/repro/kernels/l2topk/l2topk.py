@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _insert_sorted(best_d, best_i, cand_d, cand_i):
     """Insert one candidate per row into a row-sorted (TQ, k) list."""
@@ -129,7 +127,7 @@ def l2_topk_pallas(queries: jax.Array, database: jax.Array, k: int,
             pltpu.VMEM((block_q, k), jnp.float32),
             pltpu.VMEM((block_q, k), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qp, dbp, db_norm)

@@ -1,19 +1,20 @@
-"""Scalar-prefetch code-gather + LUT accumulation Pallas TPU kernel.
+"""Code-gather + LUT accumulation Pallas TPU kernel.
 
 The quantized twin of ``kernels/gather_dist``: the beam hop scores R
 neighbors per query, but instead of streaming R f32 rows of D*4 bytes it
 streams R uint8 code rows of M bytes and accumulates the per-query LUT —
-the ADC inner loop of PQ/SQ8 traversal (VSAG/ScaNN-style). Neighbor ids
-are scalar-prefetched (`pltpu.PrefetchScalarGridSpec`) so BlockSpec
-index_maps drive the DMA gather of exactly the R needed code rows, while
-the per-query LUT block stays resident across the R inner steps.
+the ADC inner loop of PQ/SQ8 traversal (VSAG/ScaNN-style). Each grid step
+takes ``TB`` queries: their (TB, R) ids are read from SMEM as DMA
+addresses (``row_gather.fetch_rows``) while the (TB, M, C) LUT block stays
+resident in VMEM.
 
-The LUT entry pick is expressed as a one-hot select over the C axis
-(iota == code), not an in-kernel gather: dynamic gathers don't vectorize
-on the VPU, whereas select+reduce does — and summing one LUT value with
-C-1 zeros is exact in f32, keeping the kernel bit-identical to the ref.
+The LUT entry pick is a one-hot select over the C axis (iota == code), not
+an in-kernel gather: dynamic gathers don't vectorize on the VPU, whereas
+select+reduce does — and summing one LUT value with C-1 zeros is exact in
+f32. The M picks are then summed left to right (``row_gather.lut_scores``),
+the order the jnp ref pins, keeping the kernel bit-identical to it.
 
-Grid: (Q, R) — one gathered code row per step; rows pipeline across steps.
+Grid: (ceil(Q / TB),).
 """
 from __future__ import annotations
 
@@ -24,23 +25,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
+from repro.kernels.row_gather import (
+    TB, compiler_params, fetch_rows, lut_scores, lut_scratch, pad_block_rows,
+    pad_table, row_scratch,
+)
 
 
-def _lut_dist_kernel(ids_ref, lut_ref, row_ref, out_ref):
-    r = pl.program_id(1)
-    m, c = lut_ref.shape[1], lut_ref.shape[2]
-    code = row_ref[...].reshape(m, 1).astype(jnp.int32)        # (M, 1)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (m, c), 1)
-    sel = jnp.where(iota == code, lut_ref[0], 0.0)             # (M, C)
-    per_m = jnp.sum(sel, axis=1)   # exact: one LUT value + C-1 zeros per m
-    # unrolled left-to-right accumulation over the (static, small) M axis —
-    # the same order XLA's minor-axis reduce gives the jnp ref, keeping the
-    # kernel bit-identical to it
-    acc = per_m[0]
-    for mm in range(1, m):
-        acc = acc + per_m[mm]
-    out_ref[0, r] = acc
+def _lut_dist_kernel(ids_ref, lut_ref, tab_ref, out_ref, tiles, rows, sem,
+                     per_m, per_m_t):
+    fetch_rows(ids_ref, tab_ref, tiles, rows, sem)
+    out_ref[...] = lut_scores(rows, lut_ref, per_m, per_m_t)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -48,28 +42,28 @@ def lut_dist_pallas(lut: jax.Array, codes: jax.Array, ids: jax.Array,
                     interpret: bool = True) -> jax.Array:
     """lut (Q, M, C) f32, codes (N, M) uint8, ids (Q, R) int32 -> (Q, R).
 
-    Negative ids are clamped to row 0 and masked to +inf outside the kernel
-    (matching beam_search's padding convention).
+    ``codes`` may arrive already padded by ``row_gather.pad_table``.
+    Negative ids read row 0 and are masked to +inf outside the kernel
+    (beam_search's padding convention).
     """
     q, m, c = lut.shape
     r = ids.shape[1]
-    safe = jnp.maximum(ids, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(q, r),
-        in_specs=[
-            pl.BlockSpec((1, m, c), lambda i, j, ids_ref: (i, 0, 0)),
-            pl.BlockSpec((1, m), lambda i, j, ids_ref: (ids_ref[i, j], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, r), lambda i, j, ids_ref: (i, 0)),
-    )
+    table = pad_table(codes)
+    ids_p = pad_block_rows(ids, -1)
     out = pl.pallas_call(
         _lut_dist_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((q, r), jnp.float32),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        grid=(ids_p.shape[0] // TB,),
+        in_specs=[
+            pl.BlockSpec((TB, r), lambda i: (i, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((TB, m, c), lambda i: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((TB, r), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct(ids_p.shape, jnp.float32),
+        scratch_shapes=(row_scratch(r, table, quantized=True)
+                        + lut_scratch(r, m)),
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
-    )(safe, lut, codes)
-    return jnp.where(ids >= 0, out, jnp.inf)
+    )(ids_p, pad_block_rows(lut, 0.0), table)
+    return jnp.where(ids >= 0, out[:q], jnp.inf)

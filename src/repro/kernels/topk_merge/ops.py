@@ -60,5 +60,5 @@ def topk_pool(ids, ds, k: int, backend: Optional[str] = None, **kw):
     kw.setdefault("interpret", jax.default_backend() != "tpu")
     out_i, out_d, _ = topk_merge_pallas(
         ids, jnp.where(ids < 0, jnp.inf, ds.astype(jnp.float32)),
-        jnp.zeros(ids.shape, bool), k, **kw)
+        jnp.zeros(ids.shape, bool), k, nearest=True, **kw)
     return out_i, out_d

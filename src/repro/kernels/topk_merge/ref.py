@@ -10,9 +10,16 @@ pre-kernel code.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
-from repro.core.build.prune import mark_dups
+
+@jax.jit
+def mark_dups(ids: jax.Array) -> jax.Array:
+    """True at positions holding a value already seen to the left."""
+    eq = ids[:, :, None] == ids[:, None, :]                    # (B, L, L)
+    tri = jnp.tril(jnp.ones(eq.shape[-2:], bool), k=-1)
+    return jnp.any(eq & tri[None], axis=-1) | (ids < 0)
 
 
 def topk_merge_ref(cur_i, cur_d, cur_f, cand_i, cand_d, k):

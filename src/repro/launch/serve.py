@@ -2,16 +2,22 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-32b
     PYTHONPATH=src python -m repro.launch.serve --arch two-tower-retrieval
+    PYTHONPATH=src python -m repro.launch.serve --arch ann-laion
     PYTHONPATH=src python -m repro.launch.serve --arch ann-laion \
-        --spec "PCA32,NSG16,EP16" --ef 48
+        --n 4000 --spec "PCA32,NSG16,EP16" --ef 48
 
-The ANN family is served purely from a factory spec string — any index the
-registry knows ("Flat", "IVF128", "IVFPQ64x16", "HNSW32", "NSG32,EP16", with
-an optional "PCA<d>," prefix) drops in with no code changes.
+The ANN family serves the arch's own deployment: the corpus width and N
+come from its config and ``--shape`` (one of its ``ANN_SHAPES``, default
+``search_300k``; ``--n`` cuts N), the data is generated from seed 0,
+and the index is the config's tuned pipeline
+(``IndexParams.from_config``). ``--spec`` swaps in any factory spec the
+registry knows ("Flat", "IVF128", "IVFPQ64x16", "HNSW32", "NSG32,EP16",
+with an optional "PCA<d>," prefix) with no code changes.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -20,28 +26,204 @@ import numpy as np
 
 from repro.configs import get_arch, list_archs
 from repro.data import clustered_vectors, lm_batch, queries_like, recsys_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import recsys, transformer
 from repro.serve.serve_step import (
     ann_search_step, lm_decode_step, lm_prefill_step, recsys_retrieval_step,
     recsys_score_step,
 )
 
+# build-time knobs the CLI can override on the config's pipeline or a spec
+ANN_OVERRIDES = ("knn_backend", "finish_backend", "dist_backend", "rerank",
+                 "hop_backend", "patience", "eps", "compact_every")
+
+
+def ann_corpus(cfg, n: int, n_queries: int, seed: int = 0):
+    """Seeded synthetic corpus at the config's width, and in-distribution
+    queries: (data (n, cfg.dim), queries (n_queries, cfg.dim))."""
+    key = jax.random.PRNGKey(seed)
+    data = clustered_vectors(key, n, cfg.dim)
+    queries = queries_like(jax.random.fold_in(key, 1), data, n_queries)
+    return data, queries
+
+
+def build_ann_index(cfg, data, key, spec=None, **overrides):
+    """The config's tuned pipeline, or the factory ``spec`` when given.
+
+    ``overrides`` (``ANN_OVERRIDES`` names; None = keep) replace fields of
+    ``IndexParams.from_config(cfg)``, or pass through to ``build_index``.
+    """
+    from repro.core import IndexParams, TunedGraphIndex, build_index
+    overrides = {k: v for k, v in overrides.items() if v is not None}
+    if spec:
+        return build_index(spec, data, key=key, **overrides)
+    params = dataclasses.replace(IndexParams.from_config(cfg), **overrides)
+    return TunedGraphIndex(params).fit(data, key)
+
+
+def serve_ragged(step, queries, max_request: int, window_s: float = 0.0,
+                 seed: int = 0):
+    """Stream ``queries`` through a ``MicroBatchQueue`` as ragged requests
+    of 1..max_request rows.
+
+    Returns (queue, answers, seconds): ``answers`` holds each request's
+    (start row, rows, answer), the answer being (dists, ids) or the
+    queue's ``SearchFailure``.
+    """
+    from repro.serve.batching import MicroBatchQueue
+    queue = MicroBatchQueue(step, window_s=window_s)
+    rng = np.random.default_rng(seed)
+    tickets, row = [], 0
+    t0 = time.perf_counter()
+    while row < queries.shape[0]:
+        n = min(int(rng.integers(1, max_request + 1)),
+                queries.shape[0] - row)
+        tickets.append((queue.submit(queries[row:row + n]), row, n))
+        row += n
+        queue.maybe_flush()
+    queue.flush()
+    seconds = time.perf_counter() - t0
+    return queue, [(row, n, queue.take(t)) for t, row, n in tickets], seconds
+
+
+def serve_ann(args, arch):
+    """Build (or restore) the ann arch's index and serve its corpus."""
+    from repro.core import (
+        FlatIndex, SearchParams, load_index, recall_at_k, save_index,
+    )
+    from repro.serve.batching import pow2_buckets
+    cfg = arch.config
+    shape = arch.shapes[args.shape]
+    n = args.n or shape.n_candidates
+    max_batch = args.batch or shape.batch
+    data, queries = ann_corpus(cfg, n, 2 * max_batch)
+    key = jax.random.PRNGKey(0)
+    overrides = {name: getattr(args, name) for name in ANN_OVERRIDES}
+    if args.restore:
+        t_load = time.perf_counter()
+        idx = load_index(args.restore)
+        print(f"restored [{getattr(idx, 'spec', args.spec)}] from "
+              f"{args.restore} in {time.perf_counter() - t_load:.2f}s "
+              f"(checksums verified, invariants validated)")
+        if hasattr(idx, "on_shard_error"):
+            idx.on_shard_error = args.on_shard_error
+    elif args.shards > 0:
+        from repro.core.distributed import ShardedFactoryIndex
+        if not args.spec:
+            raise SystemExit("--shards shards a factory spec: pass --spec")
+        idx = ShardedFactoryIndex(args.spec, n_shards=args.shards,
+                                  on_shard_error=args.on_shard_error,
+                                  **overrides)
+        idx.fit(data, key=key)
+    else:
+        t_build = time.perf_counter()
+        idx = build_ann_index(cfg, data, key, args.spec, **overrides)
+        print(f"built {args.arch} N={n} dim={cfg.dim} in "
+              f"{time.perf_counter() - t_build:.1f}s")
+    if args.snapshot:
+        save_index(idx, args.snapshot)
+        print(f"snapshot saved to {args.snapshot} "
+              f"(restore with --restore {args.snapshot})")
+    injector = None
+    if args.fault_rate > 0.0:
+        # deterministic fault-injection demo: transient faults fire
+        # UNDER the retry wrapper, so --retries absorbs them; armed
+        # only after warmup so bucket compiles are fault-free
+        from repro.serve.faults import FaultInjector
+        injector = FaultInjector(seed=args.fault_seed)
+        idx = injector.wrap_index(idx)
+    spec_label = getattr(idx, "spec", None) or args.spec or "config pipeline"
+    label = f"{args.arch} [{spec_label}]"
+    if args.buckets == "off":
+        buckets = None
+    elif args.buckets == "auto":
+        buckets = pow2_buckets(max_batch)
+    else:
+        buckets = tuple(int(b) for b in args.buckets.split(","))
+    step = ann_search_step(idx, k=cfg.k,
+                           params=SearchParams(
+                               ef_search=args.ef or cfg.ef_search),
+                           buckets=buckets,
+                           retries=args.retries,
+                           deadline_s=args.deadline)
+    _, ti = FlatIndex(data).search(queries, cfg.k)
+    if buckets is None:
+        t0 = time.perf_counter()
+        if injector is not None:
+            injector.transient_rate = args.fault_rate
+        _, ids = step(queries)
+        jax.block_until_ready(ids)
+        dt = time.perf_counter() - t0
+        print(f"{label}: {queries.shape[0] / dt:.0f} "
+              f"QPS, recall@{cfg.k}={recall_at_k(ids, ti):.4f}")
+        return
+    # bucketed serving: warm every bucket shape, then stream ragged
+    # request batches through the micro-batching queue
+    step.warmup(idx.dim)
+    if injector is not None:
+        injector.transient_rate = args.fault_rate   # arm AFTER warmup
+    n_warm = len(step.dispatched)
+    queue, answers, dt = serve_ragged(step, queries, max(1, max_batch // 8),
+                                      args.batch_window)
+    ids = np.full((queries.shape[0], cfg.k), -1, np.int64)
+    failed_tickets = 0
+    for start, rows, res in answers:
+        if res:                         # SearchFailure is falsy
+            ids[start:start + rows] = res[1]
+        else:
+            failed_tickets += 1
+    shapes = sorted(set(step.dispatched[n_warm:]))
+    print(f"{label} bucketed "
+          f"(window={args.batch_window}s, buckets={list(step.buckets)}):"
+          f" {queries.shape[0] / dt:.0f} QPS, "
+          f"recall@{cfg.k}={recall_at_k(jnp.asarray(ids), ti):.4f}, "
+          f"served shapes={shapes} (all pre-warmed)")
+    lat = queue.latency_stats()
+    print(f"  latency p50={lat['p50_ms']:.2f}ms "
+          f"p99={lat['p99_ms']:.2f}ms mean={lat['mean_ms']:.2f}ms "
+          f"over {lat['served']} queries / {lat['flushes']} flushes, "
+          f"batch occupancy={lat['mean_occupancy']:.2f}")
+    if injector is not None:
+        print(f"  faults: {injector.faults_raised} injected "
+              f"(rate={args.fault_rate}, seed={args.fault_seed}), "
+              f"{getattr(step, 'retries_used', 0)} absorbed by retry")
+    if lat["errors"] or lat["retries"] or lat["shed"] or failed_tickets:
+        print(f"  resilience: {failed_tickets} failed tickets, "
+              f"{lat['errors']} error answers, {lat['retries']} flush "
+              f"retries, {lat['shed']} shed "
+              f"(every ticket answered: result or typed failure)")
+    degraded = getattr(idx, "degraded_shards", 0)
+    if degraded:
+        print(f"  degraded: {degraded} shard(s) masked on the last "
+              f"search (on_shard_error=skip)")
+
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_archs())
-    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--batch", type=int, default=None,
+                    help="batch size (default 8); for the ann family the "
+                         "largest serving bucket (default: the shape's "
+                         "batch), requests carrying up to 1/8 of it")
     ap.add_argument("--tokens", type=int, default=16)
-    ap.add_argument("--spec", default="PCA32,NSG16,EP16",
-                    help="ANN factory spec string (ann family only)")
-    ap.add_argument("--ef", type=int, default=48,
-                    help="SearchParams.ef_search override (ann family only)")
+    ap.add_argument("--shape", default="search_300k",
+                    help="ANN_SHAPES cell whose N and batch are served "
+                         "(ann family only)")
+    ap.add_argument("--n", type=int, default=None,
+                    help="cut the corpus to N rows (ann family only; "
+                         "default: the shape's N)")
+    ap.add_argument("--spec", default=None,
+                    help="ANN factory spec string (ann family only; "
+                         "default: the config's tuned pipeline)")
+    ap.add_argument("--ef", type=int, default=None,
+                    help="SearchParams.ef_search override (ann family only; "
+                         "default: the config's ef_search)")
     ap.add_argument("--batch-window", type=float, default=0.0,
                     help="micro-batching window in seconds; 0 serves each "
                          "request batch immediately (ann family only)")
     ap.add_argument("--buckets", default="auto",
                     help="comma-separated batch-shape buckets, or 'auto' "
-                         "for powers of two up to 8x --batch, or 'off' "
+                         "for powers of two up to --batch, or 'off' "
                          "(ann family only)")
     ap.add_argument("--knn-backend", default=None,
                     choices=["exact", "nndescent", "auto"],
@@ -109,9 +291,14 @@ def main():
     ap.add_argument("--fault-seed", type=int, default=0,
                     help="seed for --fault-rate's deterministic schedule")
     args = ap.parse_args()
+    enable_compile_cache()
     spec = get_arch(args.arch)
+    if spec.family == "ann":
+        serve_ann(args, spec)
+        return
     cfg = spec.smoke_config
     key = jax.random.PRNGKey(0)
+    args.batch = args.batch or 8
 
     if spec.family == "lm":
         params = transformer.init_params(key, cfg)
@@ -143,128 +330,6 @@ def main():
         print(f"{args.arch}: scored batch {args.batch} "
               f"(mean {float(np.mean(np.asarray(s))):.4f}); retrieval "
               f"top5 ids {np.asarray(ids)}")
-    elif spec.family == "ann":
-        from repro.core import FlatIndex, SearchParams, build_index, \
-            load_index, recall_at_k, save_index
-        from repro.serve.batching import MicroBatchQueue, pow2_buckets
-        data = clustered_vectors(key, 4000, 48, n_clusters=16)
-        queries = queries_like(jax.random.PRNGKey(1), data, args.batch * 16)
-        if args.restore:
-            t_load = time.perf_counter()
-            idx = load_index(args.restore)
-            print(f"restored [{getattr(idx, 'spec', args.spec)}] from "
-                  f"{args.restore} in {time.perf_counter() - t_load:.2f}s "
-                  f"(checksums verified, invariants validated)")
-            if hasattr(idx, "on_shard_error"):
-                idx.on_shard_error = args.on_shard_error
-        elif args.shards > 0:
-            from repro.core.distributed import ShardedFactoryIndex
-            idx = ShardedFactoryIndex(args.spec, n_shards=args.shards,
-                                      knn_backend=args.knn_backend,
-                                      finish_backend=args.finish_backend,
-                                      dist_backend=args.dist_backend,
-                                      rerank=args.rerank,
-                                      hop_backend=args.hop_backend,
-                                      patience=args.patience,
-                                      eps=args.eps,
-                                      compact_every=args.compact_every,
-                                      on_shard_error=args.on_shard_error)
-            idx.fit(data, key=key)
-        else:
-            idx = build_index(args.spec, data, key=key,
-                              knn_backend=args.knn_backend,
-                              finish_backend=args.finish_backend,
-                              dist_backend=args.dist_backend,
-                              rerank=args.rerank,
-                              hop_backend=args.hop_backend,
-                              patience=args.patience,
-                              eps=args.eps,
-                              compact_every=args.compact_every)
-        if args.snapshot:
-            save_index(idx, args.snapshot)
-            print(f"snapshot saved to {args.snapshot} "
-                  f"(restore with --restore {args.snapshot})")
-        injector = None
-        if args.fault_rate > 0.0:
-            # deterministic fault-injection demo: transient faults fire
-            # UNDER the retry wrapper, so --retries absorbs them; armed
-            # only after warmup so bucket compiles are fault-free
-            from repro.serve.faults import FaultInjector
-            injector = FaultInjector(seed=args.fault_seed)
-            idx = injector.wrap_index(idx)
-        spec_label = getattr(idx, "spec", None) or args.spec
-        if args.buckets == "off":
-            buckets = None
-        elif args.buckets == "auto":
-            buckets = pow2_buckets(args.batch * 8)
-        else:
-            buckets = tuple(int(b) for b in args.buckets.split(","))
-        step = ann_search_step(idx, k=10,
-                               params=SearchParams(ef_search=args.ef),
-                               buckets=buckets,
-                               retries=args.retries,
-                               deadline_s=args.deadline)
-        _, ti = FlatIndex(data).search(queries, 10)
-        if buckets is None:
-            t0 = time.perf_counter()
-            if injector is not None:
-                injector.transient_rate = args.fault_rate
-            _, ids = step(queries)
-            jax.block_until_ready(ids)
-            dt = time.perf_counter() - t0
-            print(f"ann-laion [{spec_label}]: {queries.shape[0] / dt:.0f} "
-                  f"QPS, recall@10={recall_at_k(ids, ti):.4f}")
-            return
-        # bucketed serving: warm every bucket shape, then stream ragged
-        # request batches through the micro-batching queue
-        step.warmup(idx.dim)
-        if injector is not None:
-            injector.transient_rate = args.fault_rate   # arm AFTER warmup
-        n_warm = len(step.dispatched)
-        queue = MicroBatchQueue(step, window_s=args.batch_window)
-        rng = np.random.default_rng(0)
-        tickets, row = [], 0
-        t0 = time.perf_counter()
-        while row < queries.shape[0]:
-            n = int(rng.integers(1, args.batch + 1))     # ragged arrivals
-            n = min(n, queries.shape[0] - row)
-            tickets.append((queue.submit(queries[row:row + n]), row, n))
-            row += n
-            queue.maybe_flush()
-        queue.flush()
-        dt = time.perf_counter() - t0
-        ids = np.full((queries.shape[0], 10), -1, np.int64)
-        failed_tickets = 0
-        for ticket, start, n in tickets:
-            res = queue.take(ticket)
-            if res:                         # SearchFailure is falsy
-                ids[start:start + n] = res[1]
-            else:
-                failed_tickets += 1
-        shapes = sorted(set(step.dispatched[n_warm:]))
-        print(f"ann-laion [{spec_label}] bucketed "
-              f"(window={args.batch_window}s, buckets={list(step.buckets)}):"
-              f" {queries.shape[0] / dt:.0f} QPS, "
-              f"recall@10={recall_at_k(jnp.asarray(ids), ti):.4f}, "
-              f"served shapes={shapes} (all pre-warmed)")
-        lat = queue.latency_stats()
-        print(f"  latency p50={lat['p50_ms']:.2f}ms "
-              f"p99={lat['p99_ms']:.2f}ms mean={lat['mean_ms']:.2f}ms "
-              f"over {lat['served']} queries / {lat['flushes']} flushes, "
-              f"batch occupancy={lat['mean_occupancy']:.2f}")
-        if injector is not None:
-            print(f"  faults: {injector.faults_raised} injected "
-                  f"(rate={args.fault_rate}, seed={args.fault_seed}), "
-                  f"{getattr(step, 'retries_used', 0)} absorbed by retry")
-        if lat["errors"] or lat["retries"] or lat["shed"] or failed_tickets:
-            print(f"  resilience: {failed_tickets} failed tickets, "
-                  f"{lat['errors']} error answers, {lat['retries']} flush "
-                  f"retries, {lat['shed']} shed "
-                  f"(every ticket answered: result or typed failure)")
-        degraded = getattr(idx, "degraded_shards", 0)
-        if degraded:
-            print(f"  degraded: {degraded} shard(s) masked on the last "
-                  f"search (on_shard_error=skip)")
     else:
         raise SystemExit("gnn serving = scoring; use launch/train.py")
 

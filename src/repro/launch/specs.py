@@ -19,7 +19,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import get_arch
 from repro.configs.base import ShapeConfig
-from repro.distributed.sharding import shard_map
 from repro.core.distributed import (
     ShardedIndexArrays, input_specs_for_search, make_search_step,
     make_sharded_l2_topk,
@@ -257,7 +256,7 @@ def make_gnn_loss(cfg, mesh: Mesh):
             g = dict(zip(keys, vals))
             return local_loss(params, g)
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             wrapper, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), params),
                       *[in_spec_for(k) for k in keys]),

@@ -44,6 +44,7 @@ from repro.core.tuning import (
     TPESampler, default_space,
 )
 from repro.data import clustered_vectors, queries_like
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def merge_bench_point(path: str, point: dict) -> None:
@@ -140,6 +141,7 @@ def main():
                     help="pipeline PCA target dim (default: --dim, i.e. "
                          "projection off)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     key = jax.random.PRNGKey(0)
     data = clustered_vectors(key, args.n, args.dim, n_clusters=32)

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
 from repro.kernels.embedding_bag import embedding_bag
 
 Params = Dict[str, Any]
@@ -86,11 +85,11 @@ def make_sharded_lookup(mesh: Mesh, total_rows: int):
         rows = jnp.where(mask[..., None], rows, 0)
         return jax.lax.psum(rows, "model")
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("model", None), P(batch), P("model")),
         out_specs=P(batch, None))
-    mapped_rep = shard_map(
+    mapped_rep = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("model", None), P(), P("model")),
         out_specs=P(None, None))

@@ -5,6 +5,11 @@ Only the property tests need hypothesis; the sweeps and the traversal
 parity tests run in every environment (the tier-1 container has no
 hypothesis — gating the whole module on it once hid a broken kernel
 import)."""
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +26,21 @@ from repro.kernels.gather_dist import gather_dist
 from repro.kernels.l2topk import l2_topk
 
 SETTINGS = dict(max_examples=15, deadline=None)
+REPO = Path(__file__).resolve().parents[1]
+
+
+# ------------------------------------------------------------ import order
+@pytest.mark.parametrize("package", [
+    "beam_hop", "embedding_bag", "gather_dist", "l2topk", "lut_dist",
+    "topk_merge"])
+def test_kernel_package_imports_first(package):
+    """Each kernel package imports in a fresh interpreter before anything
+    else of the repo (no import cycle through ``core``)."""
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"))
+    out = subprocess.run(
+        [sys.executable, "-c", f"import repro.kernels.{package}"],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
 
 
 # ------------------------------------------------------------------ l2topk
