@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.distances import l2_topk
+from repro.serve.spans import span
 
 
 @dataclass
@@ -39,7 +40,8 @@ class FlatIndex:
         """
         if chunk is None and params is not None:
             chunk = params.chunk
-        return l2_topk(queries, self.data, k, chunk=chunk or 16384)
+        with span("index.search"), span("search.scan"):
+            return l2_topk(queries, self.data, k, chunk=chunk or 16384)
 
     def search_params_space(self):
         # exact search always has recall 1.0; chunk is its one (QPS-only)

@@ -28,6 +28,7 @@ from repro.core.nsg import NSGGraph, build_nsg
 from repro.core.pca import PCA, fit_pca
 from repro.core.quant import make_codec
 from repro.kernels.gather_dist import gather_dist as _gather_dist
+from repro.serve.spans import span
 
 log = logging.getLogger(__name__)
 
@@ -359,40 +360,51 @@ class TunedGraphIndex:
         eps = eps if eps is not None else self.params.eps
         compact_every = (compact_every if compact_every is not None
                          else self.params.compact_every)
-        q = self.project(queries)
-        entries = self.eps.select(q)
-        # batch-major layout: every hop is one (Q, R) gather_dist block
-        # (Pallas kernel on TPU) — exact-parity with the vmap layout.
-        bs_kw = dict(ef=max(ef, k), mode=mode, hop_backend=hop_backend,
-                     patience=patience or None, eps=eps, with_stats=True)
-        if dist_backend == "f32":
-            kb = k
-        else:
-            if self.codec is None or self.codec_backend != dist_backend:
-                self.quantize(dist_backend)
-            # keep enough ADC-ranked survivors for the exact tail to pick
-            # a true top-k from
-            kb = min(max(rerank, k), max(ef, k))
-            bs_kw.update(dist_backend=dist_backend, codes=self.codes,
-                         lut=self.codec.lut(q))
-        self.last_compaction_shapes = None
-        if compact_every:
-            shape_log: list = []
-            d, i, stats = beam_search_compacted(
-                q, self.base, self.graph.neighbors, entries, k=kb,
-                compact_every=compact_every, shape_log=shape_log, **bs_kw)
-            self.last_compaction_shapes = shape_log
-        else:
-            d, i, stats = beam_search(q, self.base, self.graph.neighbors,
-                                      entries, k=kb, layout="batched",
-                                      **bs_kw)
-        if dist_backend != "f32":
-            if rerank > 0:
-                d, i = _exact_rerank(q, self.base, i, k)
-            else:
-                d, i = d[:, :k], i[:, :k]
-        self.last_search_stats = stats
-        orig = jnp.where(i >= 0, self.kept_idx[jnp.maximum(i, 0)], -1)
+        with span("index.search"):
+            with span("search.project"):
+                q = self.project(queries)
+            with span("search.entries"):
+                entries = self.eps.select(q)
+            with span("search.traverse"):
+                # batch-major layout: every hop is one (Q, R) gather_dist
+                # block (Pallas kernel on TPU) — exact-parity with the vmap
+                # layout.
+                bs_kw = dict(ef=max(ef, k), mode=mode,
+                             hop_backend=hop_backend,
+                             patience=patience or None, eps=eps,
+                             with_stats=True)
+                if dist_backend == "f32":
+                    kb = k
+                else:
+                    if (self.codec is None
+                            or self.codec_backend != dist_backend):
+                        self.quantize(dist_backend)
+                    # keep enough ADC-ranked survivors for the exact tail to
+                    # pick a true top-k from
+                    kb = min(max(rerank, k), max(ef, k))
+                    bs_kw.update(dist_backend=dist_backend, codes=self.codes,
+                                 lut=self.codec.lut(q))
+                self.last_compaction_shapes = None
+                if compact_every:
+                    shape_log: list = []
+                    d, i, stats = beam_search_compacted(
+                        q, self.base, self.graph.neighbors, entries, k=kb,
+                        compact_every=compact_every, shape_log=shape_log,
+                        **bs_kw)
+                    self.last_compaction_shapes = shape_log
+                else:
+                    d, i, stats = beam_search(
+                        q, self.base, self.graph.neighbors, entries, k=kb,
+                        layout="batched", **bs_kw)
+                if dist_backend != "f32":
+                    if rerank > 0:
+                        d, i = _exact_rerank(q, self.base, i, k)
+                    else:
+                        d, i = d[:, :k], i[:, :k]
+                self.last_search_stats = stats
+            with span("search.ids"):
+                orig = jnp.where(i >= 0, self.kept_idx[jnp.maximum(i, 0)],
+                                 -1)
         return d, orig
 
     def search_stats(self) -> Optional[dict]:
@@ -412,20 +424,23 @@ class TunedGraphIndex:
         ``mean_hops`` / ``p99_hops`` — the per-query hop distribution whose
         tail is the batch straggler cost.
         """
-        s = self.last_search_stats
-        if s is None:
+        if self.last_search_stats is None:
             return None
-        hops = np.asarray(s.hops)
-        total = int(hops.sum())
-        wasted = int(jnp.sum(s.wasted_hops))
-        return {"hops": total,
-                "gathered": int(jnp.sum(s.gathered)),
-                "dup_gathered": int(jnp.sum(s.dup_gathered)),
-                "wasted_hops": wasted,
-                "active_fraction": float(total / max(total + wasted, 1)),
-                "mean_hops": float(hops.mean()) if hops.size else 0.0,
-                "p99_hops": float(np.percentile(hops, 99))
-                if hops.size else 0.0}
+        with span("index.stats"):
+            # one device-to-host copy of the four counters; the sums run
+            # in numpy, so reading them launches no program
+            s = jax.device_get(self.last_search_stats)
+            hops = np.asarray(s.hops)
+            total = int(hops.sum())
+            wasted = int(np.sum(s.wasted_hops))
+            return {"hops": total,
+                    "gathered": int(np.sum(s.gathered)),
+                    "dup_gathered": int(np.sum(s.dup_gathered)),
+                    "wasted_hops": wasted,
+                    "active_fraction": float(total / max(total + wasted, 1)),
+                    "mean_hops": float(hops.mean()) if hops.size else 0.0,
+                    "p99_hops": float(np.percentile(hops, 99))
+                    if hops.size else 0.0}
 
     @property
     def ntotal(self) -> int:

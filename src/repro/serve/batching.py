@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serve.spans import span
+
 
 def pow2_buckets(max_batch: int, min_bucket: int = 1) -> Tuple[int, ...]:
     """Power-of-two bucket sizes covering [1, max_batch]."""
@@ -86,15 +88,25 @@ class BucketedSearch:
             return (jnp.concatenate([d for d, _ in parts]),
                     jnp.concatenate([i for _, i in parts]))
         b = bucket_for(n, self.buckets)
-        if n < b:
-            pad = jnp.broadcast_to(queries[:1],
-                                   (b - n,) + queries.shape[1:])
-            padded = jnp.concatenate([queries, pad], axis=0)
-        else:
-            padded = queries
-        self.dispatched.append(b)
-        d, i = self.search_fn(padded)
-        return d[:n], i[:n]
+        with span("search.call", bucket=b):
+            if n < b:
+                with span("bucket.pad"):
+                    pad = jnp.broadcast_to(queries[:1],
+                                           (b - n,) + queries.shape[1:])
+                    padded = jnp.concatenate([queries, pad], axis=0)
+            else:
+                padded = queries
+            self.dispatched.append(b)
+            d, i = self.search_fn(padded)
+            with span("bucket.slice"):
+                return d[:n], i[:n]
+
+    def padded_size(self, n: int) -> int:
+        """Rows dispatched for a batch of ``n``: its max-bucket runs and
+        the bucket of the rest."""
+        full, rest = divmod(n, self.max_batch)
+        return full * self.max_batch + (bucket_for(rest, self.buckets)
+                                        if rest else 0)
 
 
 class MicroBatchQueue:
@@ -188,16 +200,23 @@ class MicroBatchQueue:
         return False
 
     def flush(self) -> None:
-        from repro.serve.resilience import SearchFailure
         if not self._pending:
             return
         # clear queue state FIRST: whatever happens below, these tickets
         # are this flush's to answer and the queue is ready for new work
         pending, self._pending = self._pending, []
-        self._pending_rows = 0
+        rows, self._pending_rows = self._pending_rows, 0
         self._oldest = None
-        batch = jnp.asarray(np.concatenate([q for _, q, _ in pending],
-                                           axis=0))
+        padded_size = getattr(self.search, "padded_size", None)
+        with span("queue.flush", flush=self.flushes, rows=rows,
+                  padded=padded_size(rows) if padded_size else rows):
+            self._serve(pending)
+
+    def _serve(self, pending) -> None:
+        from repro.serve.resilience import SearchFailure
+        with span("queue.h2d", bytes=sum(q.nbytes for _, q, _ in pending)):
+            batch = jnp.asarray(np.concatenate([q for _, q, _ in pending],
+                                               axis=0))
         n_disp = len(getattr(self.search, "dispatched", ()))
         err: Optional[BaseException] = None
         attempts = 0
@@ -205,7 +224,8 @@ class MicroBatchQueue:
             attempts = attempt + 1
             try:
                 d, i = self.search(batch)
-                d, i = np.asarray(d), np.asarray(i)
+                with span("queue.d2h"):
+                    d, i = np.asarray(d), np.asarray(i)
                 err = None
                 break
             except Exception as e:
@@ -226,12 +246,13 @@ class MicroBatchQueue:
         padded = sum(getattr(self.search, "dispatched", ())[n_disp:])
         if padded:
             self._occupancy.append(batch.shape[0] / padded)
-        row = 0
-        for ticket, q, submitted in pending:
-            n = q.shape[0]
-            self.results[ticket] = (d[row:row + n], i[row:row + n])
-            self._latency_s.extend([done - submitted] * n)
-            row += n
+        with span("queue.scatter"):
+            row = 0
+            for ticket, q, submitted in pending:
+                n = q.shape[0]
+                self.results[ticket] = (d[row:row + n], i[row:row + n])
+                self._latency_s.extend([done - submitted] * n)
+                row += n
 
     def latency_stats(self) -> dict:
         """Serving distribution so far: per-query latency percentiles (ms),
