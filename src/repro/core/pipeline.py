@@ -423,6 +423,10 @@ class TunedGraphIndex:
         hops / (hops + wasted_hops), the useful share of hop-block rows;
         ``mean_hops`` / ``p99_hops`` — the per-query hop distribution whose
         tail is the batch straggler cost.
+
+        ``fetch_share`` — gathered / (R * (hops + wasted_hops)), the share
+        of the hop's candidate slots (R per lane per loop iteration) that
+        hold a live id; the fused hop fetches a row for those slots only.
         """
         if self.last_search_stats is None:
             return None
@@ -433,11 +437,14 @@ class TunedGraphIndex:
             hops = np.asarray(s.hops)
             total = int(hops.sum())
             wasted = int(np.sum(s.wasted_hops))
+            gathered = int(np.sum(s.gathered))
+            slots = self.graph.neighbors.shape[1] * (total + wasted)
             return {"hops": total,
-                    "gathered": int(np.sum(s.gathered)),
+                    "gathered": gathered,
                     "dup_gathered": int(np.sum(s.dup_gathered)),
                     "wasted_hops": wasted,
                     "active_fraction": float(total / max(total + wasted, 1)),
+                    "fetch_share": float(gathered / max(slots, 1)),
                     "mean_hops": float(hops.mean()) if hops.size else 0.0,
                     "p99_hops": float(np.percentile(hops, 99))
                     if hops.size else 0.0}

@@ -7,9 +7,14 @@ blocks spilled to HBM between stages. Here the (Q, R) graph rows of the
 selected nodes are one XLA gather (R ids per query), and everything after
 it is one launch: each grid step takes ``TB`` queries, streams their R
 candidate rows (f32 vectors or uint8 codes, picked by a static
-``dist_backend``) HBM->VMEM (``row_gather.fetch_rows``), scores them in
-VMEM, and merges them into the resident pool with a bitonic dedup-merge —
-the (Q, R) distance block never touches HBM.
+``dist_backend``) HBM->VMEM, scores them in VMEM, and merges them into the
+resident pool with a bitonic dedup-merge — the (Q, R) distance block never
+touches HBM.
+
+Only live slots are fetched (``row_gather.fetch_live_rows``): a dead lane
+(sel < 0) and a neighbour row's -1 padding give -1 ids, and a slot with a
+-1 id costs one scalar compare, no DMA, wait or extract. Its row scratch
+keeps stale bytes, which the ``cand >= 0`` mask hides from the merge.
 
 Bit-exactness with ``ref.py`` (and therefore with the staged path) is by
 construction:
@@ -37,8 +42,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.bitonic import bitonic_by, network_width
 from repro.kernels.row_gather import (
-    TB, compiler_params, fetch_rows, l2_scores, lut_scores, lut_scratch,
-    pad_block_rows, pad_table, row_scratch,
+    TB, compiler_params, fetch_live_rows, l2_scores, lut_scores,
+    lut_scratch, pad_block_rows, pad_table, row_scratch, slot_scratch,
 )
 
 
@@ -51,9 +56,9 @@ def _stable_gt(self_t, part_t):
 
 def _beam_hop_kernel(ids_ref, cand_ref, pi_ref, pd_ref, pv_ref, q_ref,
                      tab_ref, opi_ref, opd_ref, opv_ref, stats_ref,
-                     tiles, rows, sem, *lut_scr, dist_backend: str,
+                     tiles, rows, sem, slots, *lut_scr, dist_backend: str,
                      width: int):
-    fetch_rows(ids_ref, tab_ref, tiles, rows, sem)
+    fetch_live_rows(ids_ref, tab_ref, tiles, rows, sem, slots)
     if dist_backend == "f32":
         nd = l2_scores(rows, q_ref[...])
     else:
@@ -65,7 +70,7 @@ def _beam_hop_kernel(ids_ref, cand_ref, pi_ref, pd_ref, pv_ref, q_ref,
     cand = cand_ref[...]                                  # -1 = invalid
     ids = jnp.concatenate(
         [pi_ref[...], cand, jnp.full((tb, pad), -1, jnp.int32)], axis=1)
-    ds = jnp.concatenate(
+    ds = jnp.concatenate(                         # hides unfetched rows
         [pd_ref[...], jnp.where(cand >= 0, nd, jnp.inf),
          jnp.full((tb, pad), jnp.inf, jnp.float32)], axis=1)
     vis = jnp.concatenate(
@@ -104,9 +109,9 @@ def beam_hop_pallas(sel: jax.Array, neighbors: jax.Array, pool_i: jax.Array,
 
     ``table`` ((N, D) f32 db or (N, M) uint8 codes, optionally pre-padded
     by ``row_gather.pad_table``) stays in HBM; the kernel DMAs the tiles
-    holding the R needed rows per query. Inactive lanes (sel < 0) mask
-    every candidate, so their pool state passes through unchanged (up to
-    the already-applied visited mark).
+    holding the live candidate rows of each query. Inactive lanes (sel < 0)
+    mask every candidate and fetch nothing, so their pool state passes
+    through unchanged (up to the already-applied visited mark).
     """
     nq, ef = pool_i.shape
     r = neighbors.shape[1]
@@ -116,14 +121,14 @@ def beam_hop_pallas(sel: jax.Array, neighbors: jax.Array, pool_i: jax.Array,
         jnp.where((nbr >= 0) & (sel >= 0)[:, None], nbr, -1), -1)
     table = pad_table(table)
     pool_spec = pl.BlockSpec((TB, ef), lambda i: (i, 0))
+    scratch = (row_scratch(r, table, quantized=dist_backend != "f32")
+               + [slot_scratch(r)])
     if dist_backend == "f32":
         q_spec = pl.BlockSpec((TB, q_or_lut.shape[1]), lambda i: (i, 0))
-        scratch = row_scratch(r, table, quantized=False)
     else:
         q_spec = pl.BlockSpec((TB,) + q_or_lut.shape[1:],
                               lambda i: (i, 0, 0))
-        scratch = (row_scratch(r, table, quantized=True)
-                   + lut_scratch(r, q_or_lut.shape[1]))
+        scratch += lut_scratch(r, q_or_lut.shape[1])
     nqp = cand.shape[0]
     kernel = functools.partial(_beam_hop_kernel, dist_backend=dist_backend,
                                width=width)

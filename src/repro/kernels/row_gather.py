@@ -20,6 +20,12 @@ counts bytes: the k-th wait proves that k copies' worth of bytes landed,
 not that copy k did. So no tile is read until every copy has been waited
 for.
 
+Two fetches share those loops. ``fetch_rows`` (``gather_dist``,
+``lut_dist``) fetches every slot and reads row 0 for a negative id, the
+contract of those kernels. ``fetch_live_rows`` (``beam_hop``) fetches only
+the slots whose id is >= 0: in the hop a negative id is a dead slot, which
+its merge masks, and most of a hop's slots are dead.
+
 The scores reproduce the jnp oracles bit for bit: the f32 score is the
 diff-square ``sum((x - q)^2)`` over the unpadded width, and the LUT score
 sums the M picked LUT entries left to right.
@@ -76,33 +82,71 @@ def lut_scratch(r: int, m: int):
 def fetch_rows(ids_ref, table_ref, tiles, rows, sem):
     """rows[b * R + j] <- table[max(ids[b, j], 0)] for the block's ids."""
     tb, r = ids_ref.shape
+    _fetch(tb * r, lambda k: k,
+           lambda t: jnp.maximum(ids_ref[t // r, t % r], 0),
+           table_ref, tiles, rows, sem)
 
-    def row_id(t):
-        return jnp.maximum(ids_ref[t // r, t % r], 0)
 
-    def copy(t):
-        base = pl.multiple_of(row_id(t) // TILE * TILE, TILE)
+def fetch_live_rows(ids_ref, table_ref, tiles, rows, sem, slots):
+    """rows[b * R + j] <- table[ids[b, j]] where ids[b, j] >= 0 only.
+
+    The rows of negative ids keep whatever they held: the caller masks
+    their scores. A scalar pass lists each live slot and its id in
+    ``slots`` (SMEM: TB * R slot numbers, then TB * R ids), and the
+    copies, waits and extracts run over the list. The pass is unrolled
+    over a query's R ids, all loaded before the first store, so the
+    scalar core need not wait on each load in turn; the copy loops read
+    a listed id without first reading the slot that leads to it.
+    """
+    tb, r = ids_ref.shape
+    n_all = tb * r
+
+    def listed(b, n):
+        ids = [ids_ref[b, j] for j in range(r)]
+        for j, i in enumerate(ids):
+            slots[n] = b * r + j      # n <= b * r + j: kept if i is live
+            slots[n_all + n] = i
+            n = n + (i >= 0).astype(jnp.int32)
+        return n
+
+    n = jax.lax.fori_loop(0, tb, listed, jnp.int32(0))
+    _fetch(n, lambda k: slots[k], lambda k: slots[n_all + k],
+           table_ref, tiles, rows, sem)
+
+
+def slot_scratch(r: int):
+    """Scratch for ``fetch_live_rows``'s list of TB x r slots and ids."""
+    return pltpu.SMEM((2 * TB * r,), jnp.int32)
+
+
+def _fetch(n, slot, row_id, table_ref, tiles, rows, sem):
+    """For k < n: copy, wait for and extract table row row_id(k) into
+    rows[slot(k)]."""
+
+    def copy(k):
+        base = pl.multiple_of(row_id(k) // TILE * TILE, TILE)
         return pltpu.make_async_copy(table_ref.at[pl.ds(base, TILE)],
-                                     tiles.at[t], sem.at[0])
+                                     tiles.at[slot(k)], sem.at[0])
 
-    def start(t, carry):
-        copy(t).start()
+    def start(k, carry):
+        copy(k).start()
         return carry
 
-    def wait(t, carry):
-        copy(t).wait()
+    def wait(k, carry):
+        copy(k).wait()
         return carry
 
-    def take(t, carry):
+    def take(k, carry):
+        t = slot(k)
         tile = tiles[t].astype(rows.dtype)                   # (TILE, W)
         sub = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
-        pick = jnp.where(sub == row_id(t) % TILE, tile, 0)
+        pick = jnp.where(sub == row_id(k) % TILE, tile, 0)
         rows[pl.ds(t, 1), :] = jnp.sum(pick, axis=0, keepdims=True)
         return carry
 
-    jax.lax.fori_loop(0, tb * r, start, 0)
-    jax.lax.fori_loop(0, tb * r, wait, 0)
-    jax.lax.fori_loop(0, tb * r, take, 0)
+    jax.lax.fori_loop(0, n, start, 0)
+    jax.lax.fori_loop(0, n, wait, 0)
+    jax.lax.fori_loop(0, n, take, 0)
 
 
 def l2_scores(rows, q):

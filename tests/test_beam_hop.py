@@ -93,11 +93,16 @@ def test_search_stats_surfacing(small_nsg, ann_data):
         st = idx.search_stats()
         assert set(st) >= {"hops", "gathered", "dup_gathered",
                            "wasted_hops", "active_fraction",
-                           "mean_hops", "p99_hops"}
+                           "mean_hops", "p99_hops", "fetch_share"}
         assert st["hops"] > 0
         # every hop expands at most one R-row; dups are a subset of gathers
         assert 0 < st["gathered"] <= st["hops"] * r
         assert 0 <= st["dup_gathered"] <= st["gathered"]
+        # the share of candidate slots the fused hop fetches a row for
+        assert st["fetch_share"] == st["gathered"] / (
+            r * (st["hops"] + st["wasted_hops"]))
+        # small_nsg's rows are -1-padded past their out-degree
+        assert 0 < st["fetch_share"] < 1
 
 
 def test_search_stats_work_parity_quantized(small_nsg, ann_data):

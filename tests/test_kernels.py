@@ -284,13 +284,25 @@ def _hop_inputs(seed, nq=10, n=300, d=16, r=8, ef=16):
     return sel, nbrs, pi, pd, pv, q, db
 
 
+# which candidate slots are live: the kernel fetches a row for those only
+_HOP_LIVENESS = {
+    "third_lane_dead": lambda sel, nbrs: (sel, nbrs),
+    "block_dead": lambda sel, nbrs: (sel.at[:8].set(-1), nbrs),
+    "empty_row": lambda sel, nbrs: (sel, nbrs.at[sel[1]].set(-1)),
+    "all_live": lambda sel, nbrs: (jnp.abs(sel), jnp.abs(nbrs)),
+}
+
+
+@pytest.mark.parametrize("liveness", sorted(_HOP_LIVENESS))
 @pytest.mark.parametrize("dist_backend", ["f32", "pq"])
-def test_beam_hop_pallas_bitexact_vs_ref(dist_backend):
+def test_beam_hop_pallas_bitexact_vs_ref(dist_backend, liveness):
     """One fused hop: the Pallas kernel (interpret) reproduces the jnp ref
-    bit-for-bit — ids, distances, visited marks AND work counters."""
+    bit-for-bit — ids, distances, visited marks AND work counters — for
+    every pattern of dead lanes (sel < 0) and -1 neighbour slots."""
     from repro.kernels.beam_hop import beam_hop_pallas, beam_hop_ref
 
     sel, nbrs, pi, pd, pv, q, db = _hop_inputs(3)
+    sel, nbrs = _HOP_LIVENESS[liveness](sel, nbrs)
     if dist_backend == "pq":
         m, c = 4, 16
         table = jax.random.randint(jax.random.PRNGKey(11),

@@ -64,16 +64,18 @@ def test_span_names_are_unique():
         pass                                        # no profiler: a no-op
 
 
-def _old_search_stats(s):
+def _old_search_stats(s, r):
     """The counters as the search read them before: eager sums on the
-    device."""
+    device (r: the graph's degree)."""
     hops = np.asarray(s.hops)
     total = int(hops.sum())
     wasted = int(jnp.sum(s.wasted_hops))
-    return {"hops": total, "gathered": int(jnp.sum(s.gathered)),
+    gathered = int(jnp.sum(s.gathered))
+    return {"hops": total, "gathered": gathered,
             "dup_gathered": int(jnp.sum(s.dup_gathered)),
             "wasted_hops": wasted,
             "active_fraction": float(total / max(total + wasted, 1)),
+            "fetch_share": float(gathered / max(r * (total + wasted), 1)),
             "mean_hops": float(hops.mean()) if hops.size else 0.0,
             "p99_hops": float(np.percentile(hops, 99)) if hops.size else 0.0}
 
@@ -105,8 +107,10 @@ def test_search_stats_same_dict_one_copy_no_program(small_nsg, ann_data,
     finally:
         jax.monitoring.unregister_event_duration_listener(on)
     assert events == [] and len(gets) == 2
-    assert first == again == _old_search_stats(small_nsg.last_search_stats)
+    assert first == again == _old_search_stats(
+        small_nsg.last_search_stats, small_nsg.graph.neighbors.shape[1])
     assert set(first) == {"hops", "gathered", "dup_gathered", "wasted_hops",
-                          "active_fraction", "mean_hops", "p99_hops"}
+                          "active_fraction", "fetch_share", "mean_hops",
+                          "p99_hops"}
     assert all(type(first[k]) is int for k in
                ("hops", "gathered", "dup_gathered", "wasted_hops"))
