@@ -1,10 +1,14 @@
 """Device time of one fused beam-hop launch at the ann-laion serve widths.
 
-    python3 benchmarks/hop_launch.py [--q 128 512 1024] [--launches 20]
+    python3 benchmarks/hop_launch.py [--dist f32|pq] [--q 128 512 1024]
+                                     [--launches 20]
 
-Runs ``beam_hop_pallas`` alone on a TPU over a 270,000 x 600 f32 table
+Runs ``beam_hop_pallas`` alone on a TPU over a 270,000-row table
 (pre-padded, as the search hoists it), R=32 neighbour ids per node, ef=64
-pools, for each batch size Q and two liveness patterns:
+pools. ``--dist f32`` (the default) scores 600-wide f32 rows against the
+queries; ``--dist pq`` scores PQ300x8 uint8 code rows through a (Q, 300,
+256) f32 ADC table, the quantized cell's widths. For each batch size Q, two
+liveness patterns:
 
   * ``all``: every lane live, every neighbour slot valid;
   * ``cell``: the graph cell's liveness, 43% of lanes dead (``sel < 0``)
@@ -33,10 +37,11 @@ for p in (ROOT, ROOT / "src"):
         sys.path.insert(0, str(p))
 
 N, D, R, EF, TB = 270_000, 600, 32, 64, 8
+PQ_M, PQ_C = 300, 256
 SEED = 20231003
 
 
-def hop_inputs(nq: int, pattern: str, table_rows: int):
+def hop_inputs(nq: int, pattern: str, table_rows: int, dist: str = "f32"):
     import jax
     import jax.numpy as jnp
 
@@ -51,17 +56,22 @@ def hop_inputs(nq: int, pattern: str, table_rows: int):
     pool_d = jnp.sort(jax.random.uniform(k[5], (nq, EF), jnp.float32,
                                          0, 2 * D), axis=1)
     pool_v = jax.random.bernoulli(k[6], 0.5, (nq, EF))
-    q = jax.random.normal(k[7], (nq, D), jnp.float32)
+    if dist == "f32":
+        q = jax.random.normal(k[7], (nq, D), jnp.float32)
+    else:                       # an ADC table: squared sub-distances
+        q = jax.random.uniform(k[7], (nq, PQ_M, PQ_C), jnp.float32, 0, 4)
     return sel, nbr, pool_i, pool_d, pool_v, q
 
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--dist", choices=("f32", "pq"), default="f32")
     ap.add_argument("--q", type=int, nargs="+", default=[128, 512, 1024])
     ap.add_argument("--launches", type=int, default=20)
     args = ap.parse_args()
 
     import jax
+    import jax.numpy as jnp
     import numpy as np
     from bench import trace
     from repro.kernels.beam_hop import beam_hop_pallas, beam_hop_ref
@@ -71,16 +81,21 @@ def main() -> None:
     if dev.platform != "tpu":
         sys.exit(f"hop_launch: needs a TPU, JAX's platform is "
                  f"{dev.platform!r}")
-    db = jax.random.normal(jax.random.PRNGKey(SEED), (N, D))
+    if args.dist == "f32":
+        db = jax.random.normal(jax.random.PRNGKey(SEED), (N, D))
+    else:
+        db = jax.random.randint(jax.random.PRNGKey(SEED), (N, PQ_M), 0,
+                                PQ_C).astype(jnp.uint8)
     table = jax.block_until_ready(pad_table(db))
+    kw = {"dist_backend": args.dist}
     results = []
     for nq in args.q:
         for pattern in ("all", "cell"):
-            sel, nbr, pi, pd, pv, q = hop_inputs(nq, pattern, N)
+            sel, nbr, pi, pd, pv, q = hop_inputs(nq, pattern, N, args.dist)
             hop_args = (sel, nbr, pi, pd, pv, q, table)
             got = jax.block_until_ready(
-                beam_hop_pallas(*hop_args, interpret=False))
-            want = beam_hop_ref(*hop_args[:-1], db)
+                beam_hop_pallas(*hop_args, interpret=False, **kw))
+            want = beam_hop_ref(*hop_args[:-1], db, **kw)
             exact = all(bool(np.array_equal(np.asarray(a), np.asarray(b)))
                         for a, b in zip(want, got))
             live = float(np.mean((np.asarray(nbr)[np.maximum(
@@ -89,7 +104,8 @@ def main() -> None:
                 t = time.perf_counter()
                 with jax.profiler.trace(tmp):
                     for _ in range(args.launches):
-                        out = beam_hop_pallas(*hop_args, interpret=False)
+                        out = beam_hop_pallas(*hop_args, interpret=False,
+                                              **kw)
                     jax.block_until_ready(out)
                 host_s = time.perf_counter() - t
                 ops, _, _ = trace.load(Path(tmp))
@@ -101,7 +117,8 @@ def main() -> None:
             op, hop = next((k, v) for k, v in by_op.items()
                            if k.startswith("%beam_hop_pallas"))
             per_launch_us = sum(hop) / len(hop) / 1e3
-            row = {"q": nq, "pattern": pattern, "bit_exact": exact,
+            row = {"dist": args.dist, "q": nq, "pattern": pattern,
+                   "bit_exact": exact,
                    "live_share": round(live, 4), "launches": len(hop),
                    "launch_us": round(per_launch_us, 2),
                    "program_us": round(sum(map(sum, by_op.values()))
@@ -111,8 +128,9 @@ def main() -> None:
                    "host_launch_us": round(host_s / args.launches * 1e6, 1)}
             results.append(row)
             print(" ".join(f"{k}={v}" for k, v in row.items()), flush=True)
-    print(json.dumps({"device_kind": dev.device_kind, "n": N, "d": D,
-                      "r": R, "ef": EF, "cases": results}))
+    width = {"d": D} if args.dist == "f32" else {"m": PQ_M, "c": PQ_C}
+    print(json.dumps({"device_kind": dev.device_kind, "dist": args.dist,
+                      "n": N, **width, "r": R, "ef": EF, "cases": results}))
     if not all(r["bit_exact"] for r in results):
         sys.exit("hop_launch: a case differs from beam_hop_ref")
 

@@ -1,7 +1,8 @@
 """k-means (kmeans++ init + Lloyd) — entry-point clustering (paper §3.1, knob k).
 
-Also reused by the IVF baseline's coarse quantizer and PQ codebook training.
-All distance work routes through the MXU-friendly chunked path.
+Also reused by the IVF baseline's coarse quantizer and PQ codebook training
+(``kmeans_blocks``: one k-means per column block, all in one program). All
+distance work routes through the MXU-friendly chunked path.
 """
 from __future__ import annotations
 
@@ -68,3 +69,31 @@ def kmeans(key: jax.Array, x: jax.Array, k: int, iters: int = 10,
         raise ValueError(f"k={k} out of range for n={x.shape[0]}")
     cents, assign, inertia = _lloyd(key, x, k, iters, chunk)
     return KMeansResult(cents, assign, inertia)
+
+
+@functools.partial(jax.jit, static_argnames=("m", "k", "iters", "chunk"))
+def _lloyd_blocks(key, x, m: int, k: int, iters: int, chunk: int):
+    dsub = x.shape[1] // m
+
+    def one(j):
+        xj = jax.lax.dynamic_slice_in_dim(x, j * dsub, dsub, axis=1)
+        return _lloyd(jax.random.fold_in(key, j), xj, k, iters, chunk)
+
+    return jax.lax.map(one, jnp.arange(m))
+
+
+def kmeans_blocks(key: jax.Array, x: jax.Array, m: int, k: int,
+                  iters: int = 10, chunk: int = 16384) -> KMeansResult:
+    """k-means of each of the ``m`` equal column blocks of ``x`` (N, D).
+
+    Block j is ``kmeans(fold_in(key, j), x[:, j*D/m:(j+1)*D/m], k, iters)``,
+    the same arithmetic, but the blocks run one after another inside one
+    program: no program per block, and no slice compiled per block (PQ
+    codebook training). Returns centroids (m, k, D/m), assignments (m, N),
+    inertia (m,).
+    """
+    if x.shape[1] % m:
+        raise ValueError(f"m={m} does not divide D={x.shape[1]}")
+    if k < 1 or k > x.shape[0]:
+        raise ValueError(f"k={k} out of range for n={x.shape[0]}")
+    return KMeansResult(*_lloyd_blocks(key, x, m, k, iters, chunk))

@@ -382,8 +382,10 @@ class TunedGraphIndex:
                     # keep enough ADC-ranked survivors for the exact tail to
                     # pick a true top-k from
                     kb = min(max(rerank, k), max(ef, k))
+                    with span("search.lut"):
+                        lut = self.codec.lut(q)
                     bs_kw.update(dist_backend=dist_backend, codes=self.codes,
-                                 lut=self.codec.lut(q))
+                                 lut=lut)
                 self.last_compaction_shapes = None
                 if compact_every:
                     shape_log: list = []
@@ -398,7 +400,8 @@ class TunedGraphIndex:
                         layout="batched", **bs_kw)
                 if dist_backend != "f32":
                     if rerank > 0:
-                        d, i = _exact_rerank(q, self.base, i, k)
+                        with span("search.rerank"):
+                            d, i = _exact_rerank(q, self.base, i, k)
                     else:
                         d, i = d[:, :k], i[:, :k]
                 self.last_search_stats = stats

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.distances import l2_topk
-from repro.core.kmeans import kmeans
+from repro.core.kmeans import kmeans_blocks
 
 
 @runtime_checkable
@@ -107,9 +107,10 @@ def pq_decode(codes: jax.Array, codebooks: jax.Array) -> jax.Array:
 class PQCodec:
     """Product quantizer: M sub-spaces, C<=256 k-means centroids each.
 
-    Training reuses ``core.kmeans`` per sub-space with the same key
-    folding as the standalone PQ baseline (``core/pq.py``), which now
-    delegates here — the codebooks and codes are bit-identical.
+    Training runs ``core.kmeans`` on every sub-space (key ``fold_in(key,
+    j)`` for sub-space j) in one program (``kmeans_blocks``). The
+    standalone PQ baseline (``core/pq.py``) delegates here, so the
+    codebooks and codes are the same.
     """
 
     def __init__(self, m: int, n_centroids: int = 256):
@@ -128,15 +129,13 @@ class PQCodec:
             raise ValueError(
                 f"PQ m={self.m} does not divide dim={d}; pick m from the "
                 f"divisors of the (post-PCA) dimensionality")
-        dsub = d // self.m
-        sub = data.reshape(n, self.m, dsub)
-        books = []
-        for j in range(self.m):
-            km = kmeans(jax.random.fold_in(key, j), sub[:, j],
-                        min(self.n_centroids, n), iters=iters)
-            books.append(km.centroids)
-        self.codebooks = jnp.stack(books)
-        self.codes = self.encode(data)
+        # sub-space j is kmeans(fold_in(key, j), its columns), all M in one
+        # program; its final assignments are encode(data)'s column j, by the
+        # same nearest-centroid arithmetic
+        km = kmeans_blocks(key, data, self.m, min(self.n_centroids, n),
+                           iters=iters)
+        self.codebooks = km.centroids
+        self.codes = km.assignments.T.astype(jnp.uint8)
         return self
 
     def encode(self, data: jax.Array) -> jax.Array:

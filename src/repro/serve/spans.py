@@ -17,6 +17,8 @@ Nesting, outermost first::
           search.project        graph: PCA projection of the queries
           search.entries        graph: entry-point selection
           search.traverse       graph: the beam search
+            search.lut          quantized graph: the per-query ADC table
+            search.rerank       quantized graph: the exact float32 tail
           search.ids            graph: internal ids mapped to the corpus's
           search.scan           flat: the chunked scan and top-k
         bucket.slice            the padding rows cut off the answers
@@ -33,8 +35,9 @@ import jax
 
 SPANS = ("queue.flush", "queue.h2d", "search.call", "bucket.pad",
          "index.search", "search.project", "search.entries",
-         "search.traverse", "search.ids", "search.scan", "bucket.slice",
-         "queue.d2h", "queue.scatter", "index.stats")
+         "search.traverse", "search.lut", "search.rerank", "search.ids",
+         "search.scan", "bucket.slice", "queue.d2h", "queue.scatter",
+         "index.stats")
 
 
 def span(name: str, **args) -> jax.profiler.TraceAnnotation:

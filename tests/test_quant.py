@@ -517,6 +517,29 @@ def test_pqindex_delegates_to_codec_bit_identical():
     np.testing.assert_array_equal(np.asarray(d), np.asarray(d_old))
 
 
+@pytest.mark.parametrize("n,d,m,c", [(1200, 32, 16, 64), (600, 600, 300, 256)])
+def test_pq_fit_is_one_kmeans_per_subspace(n, d, m, c):
+    """PQCodec.fit trains every sub-space in one program: the codebooks of
+    a k-means per sub-space (key fold_in(key, j)) run one after another,
+    and the codes those codebooks encode."""
+    from repro.core.kmeans import kmeans
+    key = jax.random.PRNGKey(7)
+    data = jax.random.normal(jax.random.PRNGKey(n), (n, d)) \
+        * jnp.linspace(1.0, 0.1, d)
+    codec = PQCodec(m, c).fit(data, key=key, iters=4)
+    sub = data.reshape(n, m, d // m)
+    looped = PQCodec(m, c)
+    looped.codebooks = jnp.stack([
+        kmeans(jax.random.fold_in(key, j), sub[:, j], c, iters=4).centroids
+        for j in range(m)])
+    np.testing.assert_allclose(np.asarray(codec.codebooks),
+                               np.asarray(looped.codebooks), rtol=0,
+                               atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(codec.codes),
+                                  np.asarray(looped.encode(data)))
+    assert codec.codes.dtype == jnp.uint8 and codec.codes.shape == (n, m)
+
+
 def test_ivfpq_still_composes():
     """IVF-PQ reads pq.codebooks/pq.codes — the delegation must keep it."""
     data = jax.random.normal(jax.random.PRNGKey(9), (600, 16))

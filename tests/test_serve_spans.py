@@ -114,3 +114,51 @@ def test_search_stats_same_dict_one_copy_no_program(small_nsg, ann_data,
                           "p99_hops"}
     assert all(type(first[k]) is int for k in
                ("hops", "gathered", "dup_gathered", "wasted_hops"))
+
+
+def _host_spans(trace_dir: Path):
+    """(name, start_ns, end_ns) of every host event of the newest trace."""
+    from jax.profiler import ProfileData
+    f = sorted(trace_dir.rglob("*.xplane.pb"))[-1]
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(str(f)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+@pytest.fixture(scope="module")
+def small_pq_nsg(ann_data):
+    from repro.core import build_vanilla_nsg
+    return build_vanilla_nsg(ann_data["data"], degree=12, ef_search=48,
+                             build_knn_k=12, build_candidates=32,
+                             dist_backend="pq", rerank=32)
+
+
+@pytest.mark.parametrize("dist", ["pq", "f32"])
+def test_quantized_search_spans_its_table_and_rerank(small_pq_nsg, ann_data,
+                                                     tmp_path, dist):
+    """A PQ search opens ``search.lut`` and then ``search.rerank``, both
+    inside ``search.traverse``; an f32 search over the same index opens
+    neither."""
+    q = ann_data["queries"][:9]
+    small_pq_nsg.search(q, 10, dist_backend=dist)   # compiled outside
+    with jax.profiler.trace(str(tmp_path)):
+        small_pq_nsg.search(q, 10, dist_backend=dist)
+    events = _host_spans(tmp_path)
+    by_name = {}
+    for name, s, e in events:
+        by_name.setdefault(name, []).append((s, e))
+    traverse, = by_name["search.traverse"]
+    if dist == "f32":
+        assert "search.lut" not in by_name and "search.rerank" not in by_name
+        return
+    (lut,), (rerank,) = by_name["search.lut"], by_name["search.rerank"]
+    for s, e in (lut, rerank):
+        assert traverse[0] <= s <= e <= traverse[1]
+    assert lut[1] <= rerank[0]
+
+
+def test_spans_name_the_quantized_search_stages():
+    assert {"search.lut", "search.rerank"} <= set(SPANS)
+    assert SPANS.index("search.traverse") < SPANS.index("search.lut") \
+        < SPANS.index("search.rerank") < SPANS.index("search.ids")
