@@ -1,14 +1,15 @@
 """Device time of one fused beam-hop launch at the ann-laion serve widths.
 
     python3 benchmarks/hop_launch.py [--dist f32|pq] [--q 128 512 1024]
-                                     [--launches 20]
+                                     [--launches 20] [--baseline FILE]
 
 Runs ``beam_hop_pallas`` alone on a TPU over a 270,000-row table
 (pre-padded, as the search hoists it), R=32 neighbour ids per node, ef=64
 pools. ``--dist f32`` (the default) scores 600-wide f32 rows against the
 queries; ``--dist pq`` scores PQ300x8 uint8 code rows through a (Q, 300,
-256) f32 ADC table, the quantized cell's widths. For each batch size Q, two
-liveness patterns:
+256) f32 ADC table, the quantized cell's widths, handed to the kernel
+centroid-major ((Q, 256, 300), made once, as the search hoists it). For
+each batch size Q, two liveness patterns:
 
   * ``all``: every lane live, every neighbour slot valid;
   * ``cell``: the graph cell's liveness, 43% of lanes dead (``sel < 0``)
@@ -20,7 +21,10 @@ Each case is checked against ``beam_hop_ref`` bit for bit, warmed, then run
 kernel op's device time per launch, per grid step (Q / 8 queries) and per
 candidate slot (Q x R), the device time of all the launch's ops, and the
 share of slots that are live. The last
-line is a JSON object with every case. Exits non-zero off a TPU.
+line is a JSON object with every case. ``--baseline FILE`` reads another
+run's output (its last line; say, the same command on an older tree) and
+adds to each case ``step_ratio``, this step time over that run's for the
+same case. Exits non-zero off a TPU.
 """
 from __future__ import annotations
 
@@ -68,14 +72,21 @@ def main() -> None:
     ap.add_argument("--dist", choices=("f32", "pq"), default="f32")
     ap.add_argument("--q", type=int, nargs="+", default=[128, 512, 1024])
     ap.add_argument("--launches", type=int, default=20)
+    ap.add_argument("--baseline", type=Path,
+                    help="another run's output, for each case's step_ratio")
     args = ap.parse_args()
+    base = {}
+    if args.baseline:
+        last = args.baseline.read_text().strip().splitlines()[-1]
+        base = {(c["q"], c["pattern"]): c["step_us"]
+                for c in json.loads(last)["cases"]}
 
     import jax
     import jax.numpy as jnp
     import numpy as np
     from bench import trace
     from repro.kernels.beam_hop import beam_hop_pallas, beam_hop_ref
-    from repro.kernels.row_gather import pad_table
+    from repro.kernels.row_gather import centroid_major, pad_table
 
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -92,10 +103,12 @@ def main() -> None:
     for nq in args.q:
         for pattern in ("all", "cell"):
             sel, nbr, pi, pd, pv, q = hop_inputs(nq, pattern, N, args.dist)
-            hop_args = (sel, nbr, pi, pd, pv, q, table)
+            q_kernel = q if args.dist == "f32" else jax.block_until_ready(
+                centroid_major(q))
+            hop_args = (sel, nbr, pi, pd, pv, q_kernel, table)
             got = jax.block_until_ready(
                 beam_hop_pallas(*hop_args, interpret=False, **kw))
-            want = beam_hop_ref(*hop_args[:-1], db, **kw)
+            want = beam_hop_ref(sel, nbr, pi, pd, pv, q, db, **kw)
             exact = all(bool(np.array_equal(np.asarray(a), np.asarray(b)))
                         for a, b in zip(want, got))
             live = float(np.mean((np.asarray(nbr)[np.maximum(
@@ -126,6 +139,9 @@ def main() -> None:
                    "step_us": round(per_launch_us / (nq // TB), 3),
                    "slot_ns": round(per_launch_us * 1e3 / (nq * R), 2),
                    "host_launch_us": round(host_s / args.launches * 1e6, 1)}
+            if (nq, pattern) in base:
+                row["step_ratio"] = round(row["step_us"]
+                                          / base[(nq, pattern)], 4)
             results.append(row)
             print(" ".join(f"{k}={v}" for k, v in row.items()), flush=True)
     width = {"d": D} if args.dist == "f32" else {"m": PQ_M, "c": PQ_C}

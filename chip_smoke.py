@@ -80,6 +80,7 @@ def kernel_parity(n_rows: int) -> None:
     from repro.kernels.beam_hop import beam_hop
     from repro.kernels.gather_dist import gather_dist
     from repro.kernels.lut_dist import lut_dist
+    from repro.kernels.row_gather import centroid_major
 
     arch = get_arch("ann-laion")
     cfg, q = arch.config, arch.shapes["search_300k"].batch
@@ -111,14 +112,17 @@ def kernel_parity(n_rows: int) -> None:
     log(f"parity: {n_rows} rows, d'={d}, PQ{m}, R={r}, ef={ef}, Q={q} "
         f"({time.perf_counter() - t0:.1f}s to make)")
 
+    # the kernels take the ADC table centroid-major, the refs (Q, M, C)
+    lut_for = {"jnp": lut,
+               "pallas": jax.block_until_ready(centroid_major(lut))}
     cases = {
         "gather_dist": lambda b: [gather_dist(queries, db, ids, backend=b)],
-        "lut_dist": lambda b: [lut_dist(lut, codes, ids, backend=b)],
+        "lut_dist": lambda b: [lut_dist(lut_for[b], codes, ids, backend=b)],
         "beam_hop_f32": lambda b: beam_hop(
             sel, neighbors, pool_i, pool_d, pool_v, queries, db,
             dist_backend="f32", backend=b),
         "beam_hop_pq": lambda b: beam_hop(
-            sel, neighbors, pool_i, pool_d, pool_v, lut, codes,
+            sel, neighbors, pool_i, pool_d, pool_v, lut_for[b], codes,
             dist_backend="pq", backend=b),
     }
     bad = []

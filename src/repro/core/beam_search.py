@@ -76,7 +76,7 @@ from repro.kernels.beam_hop import beam_hop as _kernel_beam_hop
 from repro.kernels.beam_hop import merge_one
 from repro.kernels.gather_dist import gather_dist as _kernel_gather_dist
 from repro.kernels.lut_dist import lut_dist as _kernel_lut_dist
-from repro.kernels.row_gather import pad_table
+from repro.kernels.row_gather import centroid_major, pad_table
 
 
 class BeamStats(NamedTuple):
@@ -343,7 +343,7 @@ def _batched_hop_setup(queries, db, neighbors, *, gather_dist,
     Returns ``(gd, body)``; ``gd`` also seeds the pool's entry distances.
     Under a quantized ``dist_backend`` the ``queries`` argument is only a
     placeholder for ``gd``'s signature (the LUT carries the per-query
-    operand). ``db``/``codes`` arrive through ``_kernel_tables``.
+    operand). ``db``/``codes``/``lut`` arrive through ``_kernel_tables``.
     """
     hop = resolve_hop_backend(hop_backend)
     if gather_dist is not None and hop == "fused":
@@ -353,10 +353,6 @@ def _batched_hop_setup(queries, db, neighbors, *, gather_dist,
             raise ValueError(
                 "hop_backend='fused' cannot honor a custom gather_dist "
                 "callable (distances are computed in-kernel)")
-    if dist_backend != "f32" and (codes is None or lut is None):
-        raise ValueError(
-            f"dist_backend={dist_backend!r} needs codes and lut "
-            f"(encode the db with a core.quant codec first)")
     if dist_backend != "f32":
         backend = resolve_gather_backend(gather_backend) or "jnp"
         gd = lambda q, db_, ids: _kernel_lut_dist(lut, codes, ids,
@@ -391,22 +387,30 @@ def _batched_hop_setup(queries, db, neighbors, *, gather_dist,
     return gd, body
 
 
-def _kernel_tables(db, codes, *, gather_dist, gather_backend, dist_backend):
-    """The row table the hops read, in the Pallas kernels' tile layout.
+def _kernel_tables(db, codes, lut, *, gather_dist, gather_backend,
+                   dist_backend):
+    """The row table and ADC table the hops read, in the Pallas kernels'
+    layouts.
 
-    The kernels DMA whole (8, 128) tiles (``row_gather.pad_table``). Padded
-    by the kernels themselves, inside the hop loop, the table would be
+    The kernels DMA whole (8, 128) tiles (``row_gather.pad_table``) and
+    take the ADC table centroid-major (``row_gather.centroid_major``).
+    Made by the kernels themselves, inside the hop loop, either would be
     copied on every hop (XLA sinks the pad into the loop body), so each
-    search pads it here, once, before its first hop: a no-op for an
-    aligned table or off the Pallas path.
+    search makes them here, once, before its first hop: a no-op for an
+    aligned f32 table or off the Pallas path. A quantized search scores
+    with ``kernels/lut_dist`` whatever ``gather_dist`` is.
     """
-    if gather_dist is None and resolve_gather_backend(gather_backend) == \
-            "pallas":
-        if dist_backend == "f32":
-            db = pad_table(db)
-        else:
-            codes = pad_table(codes)
-    return db, codes
+    if dist_backend != "f32" and (codes is None or lut is None):
+        raise ValueError(
+            f"dist_backend={dist_backend!r} needs codes and lut "
+            f"(encode the db with a core.quant codec first)")
+    pallas = resolve_gather_backend(gather_backend) == "pallas"
+    if dist_backend != "f32":
+        if pallas:
+            codes, lut = pad_table(codes), centroid_major(lut)
+    elif pallas and gather_dist is None:
+        db = pad_table(db)
+    return db, codes, lut
 
 
 def _seed_batched(queries, db, neighbors, entry_ids, ef, gd):
@@ -500,9 +504,9 @@ def _beam_search_batched(queries, db, neighbors, entry_ids, *, ef, k,
                          dist_backend="f32", codes=None, lut=None,
                          hop_backend=None, patience=None, eps=0.0,
                          with_stats=False):
-    db, codes = _kernel_tables(db, codes, gather_dist=gather_dist,
-                               gather_backend=gather_backend,
-                               dist_backend=dist_backend)
+    db, codes, lut = _kernel_tables(db, codes, lut, gather_dist=gather_dist,
+                                    gather_backend=gather_backend,
+                                    dist_backend=dist_backend)
     gd, body = _batched_hop_setup(
         queries, db, neighbors, gather_dist=gather_dist,
         gather_backend=gather_backend, dist_backend=dist_backend,
@@ -638,9 +642,9 @@ def beam_search_compacted(queries: jax.Array, db: jax.Array,
     slice_kw = dict(gather_dist=gather_dist, gather_backend=gather_backend,
                     dist_backend=dist_backend, hop_backend=hop_backend)
     # padded once for every slice of this search, not once per slice
-    db, codes = _kernel_tables(db, codes, gather_dist=gather_dist,
-                               gather_backend=gather_backend,
-                               dist_backend=dist_backend)
+    db, codes, lut = _kernel_tables(db, codes, lut, gather_dist=gather_dist,
+                                    gather_backend=gather_backend,
+                                    dist_backend=dist_backend)
 
     b0 = bucket_for(nq, buckets)
     q_cur = pad_rows(jnp.asarray(queries), b0)

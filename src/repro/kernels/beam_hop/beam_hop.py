@@ -14,13 +14,17 @@ touches HBM.
 Only live slots are fetched (``row_gather.fetch_live_rows``): a dead lane
 (sel < 0) and a neighbour row's -1 padding give -1 ids, and a slot with a
 -1 id costs one scalar compare, no DMA, wait or extract. Its row scratch
-keeps stale bytes, which the ``cand >= 0`` mask hides from the merge.
+keeps stale bytes, which the ``cand >= 0`` mask hides from the merge. The
+quantized branch takes the ADC table centroid-major, (Q, C, M)
+(``row_gather.centroid_major``, made once per search), and scores a
+query's R code rows as one block, by a select per centroid; a query with
+no live slot skips its scoring, and the same mask hides its stale scores.
 
 Bit-exactness with ``ref.py`` (and therefore with the staged path) is by
 construction:
 
   * f32 distances use the diff-square form of ``kernels/gather_dist``;
-    PQ/int8 use ``kernels/lut_dist``'s one-hot select + left-to-right
+    PQ/int8 use ``kernels/lut_dist``'s per-centroid select + left-to-right
     accumulation over M (the scores are the same ``row_gather`` code);
   * a candidate is a duplicate when any pool lane holds its id, found by
     rotating the (pool | candidates) id row past itself;
@@ -58,11 +62,11 @@ def _beam_hop_kernel(ids_ref, cand_ref, pi_ref, pd_ref, pv_ref, q_ref,
                      tab_ref, opi_ref, opd_ref, opv_ref, stats_ref,
                      tiles, rows, sem, slots, *lut_scr, dist_backend: str,
                      width: int):
-    fetch_live_rows(ids_ref, tab_ref, tiles, rows, sem, slots)
+    live = fetch_live_rows(ids_ref, tab_ref, tiles, rows, sem, slots)
     if dist_backend == "f32":
         nd = l2_scores(rows, q_ref[...])
     else:
-        nd = lut_scores(rows, q_ref, *lut_scr)
+        nd = lut_scores(rows, q_ref, *lut_scr, live=live)
 
     tb, ef = pi_ref.shape
     r = cand_ref.shape[1]
@@ -107,11 +111,14 @@ def beam_hop_pallas(sel: jax.Array, neighbors: jax.Array, pool_i: jax.Array,
                     interpret: bool = True):
     """One fused hop over all Q lanes; see ``ref.beam_hop_ref`` for shapes.
 
-    ``table`` ((N, D) f32 db or (N, M) uint8 codes, optionally pre-padded
-    by ``row_gather.pad_table``) stays in HBM; the kernel DMAs the tiles
-    holding the live candidate rows of each query. Inactive lanes (sel < 0)
-    mask every candidate and fetch nothing, so their pool state passes
-    through unchanged (up to the already-applied visited mark).
+    ``q_or_lut`` is the (Q, D) queries, or for "pq"/"int8" the ADC table
+    centroid-major, (Q, C, M) (``row_gather.centroid_major`` of the ref's
+    (Q, M, C) one). ``table`` ((N, D) f32 db or (N, M) uint8 codes,
+    optionally pre-padded by ``row_gather.pad_table``) stays in HBM; the
+    kernel DMAs the tiles holding the live candidate rows of each query.
+    Inactive lanes (sel < 0) mask every candidate and fetch and score
+    nothing, so their pool state passes through unchanged (up to the
+    already-applied visited mark).
     """
     nq, ef = pool_i.shape
     r = neighbors.shape[1]
@@ -128,7 +135,7 @@ def beam_hop_pallas(sel: jax.Array, neighbors: jax.Array, pool_i: jax.Array,
     else:
         q_spec = pl.BlockSpec((TB,) + q_or_lut.shape[1:],
                               lambda i: (i, 0, 0))
-        scratch += lut_scratch(r, q_or_lut.shape[1])
+        scratch += lut_scratch(r, q_or_lut.shape[2])
     nqp = cand.shape[0]
     kernel = functools.partial(_beam_hop_kernel, dist_backend=dist_backend,
                                width=width)

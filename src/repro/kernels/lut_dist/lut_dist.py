@@ -5,14 +5,15 @@ neighbors per query, but instead of streaming R f32 rows of D*4 bytes it
 streams R uint8 code rows of M bytes and accumulates the per-query LUT —
 the ADC inner loop of PQ/SQ8 traversal (VSAG/ScaNN-style). Each grid step
 takes ``TB`` queries: their (TB, R) ids are read from SMEM as DMA
-addresses (``row_gather.fetch_rows``) while the (TB, M, C) LUT block stays
-resident in VMEM.
+addresses (``row_gather.fetch_rows``) while the (TB, C, M) centroid-major
+LUT block stays resident in VMEM.
 
-The LUT entry pick is a one-hot select over the C axis (iota == code), not
-an in-kernel gather: dynamic gathers don't vectorize on the VPU, whereas
-select+reduce does — and summing one LUT value with C-1 zeros is exact in
-f32. The M picks are then summed left to right (``row_gather.lut_scores``),
-the order the jnp ref pins, keeping the kernel bit-identical to it.
+The LUT entry pick is a select, not an in-kernel gather (dynamic gathers
+don't vectorize on the VPU): for each centroid c, one LUT row is selected
+into a query's (R, M) block of picks wherever its codes equal c, so each
+pick is the LUT value itself. The M picks are then summed left to right
+(``row_gather.lut_scores``), the order the jnp ref pins, keeping the
+kernel bit-identical to it.
 
 Grid: (ceil(Q / TB),).
 """
@@ -40,13 +41,15 @@ def _lut_dist_kernel(ids_ref, lut_ref, tab_ref, out_ref, tiles, rows, sem,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def lut_dist_pallas(lut: jax.Array, codes: jax.Array, ids: jax.Array,
                     interpret: bool = True) -> jax.Array:
-    """lut (Q, M, C) f32, codes (N, M) uint8, ids (Q, R) int32 -> (Q, R).
+    """lut (Q, C, M) f32, codes (N, M) uint8, ids (Q, R) int32 -> (Q, R).
 
-    ``codes`` may arrive already padded by ``row_gather.pad_table``.
+    ``lut`` is the ADC table centroid-major (``row_gather.centroid_major``
+    of ``lut_dist_ref``'s (Q, M, C) one). ``codes`` may arrive already
+    padded by ``row_gather.pad_table``.
     Negative ids read row 0 and are masked to +inf outside the kernel
     (beam_search's padding convention).
     """
-    q, m, c = lut.shape
+    q, c, m = lut.shape
     r = ids.shape[1]
     table = pad_table(codes)
     ids_p = pad_block_rows(ids, -1)
@@ -56,7 +59,7 @@ def lut_dist_pallas(lut: jax.Array, codes: jax.Array, ids: jax.Array,
         in_specs=[
             pl.BlockSpec((TB, r), lambda i: (i, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((TB, m, c), lambda i: (i, 0, 0)),
+            pl.BlockSpec((TB, c, m), lambda i: (i, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((TB, r), lambda i: (i, 0)),

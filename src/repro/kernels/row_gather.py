@@ -29,8 +29,20 @@ its merge masks, and most of a hop's slots are dead.
 The scores reproduce the jnp oracles bit for bit: the f32 score is the
 diff-square ``sum((x - q)^2)`` over the unpadded width, and the LUT score
 sums the M picked LUT entries left to right.
+
+The LUT score picks entries by selection, not by gather: dynamic gathers
+don't vectorize on the VPU. The kernels take the ADC table centroid-major,
+(Q, C, M) (``centroid_major``), so centroid c's M sub-distances are one
+lane row. For each query, ``lut_scores`` keeps its R code rows as one
+(R, M) block and, for c = 0..C-1, selects row c of the table wherever a
+code equals c: compares and selects over a few vregs, the row broadcast
+across sublanes. Each (slot, m) is written by exactly one c, so every
+pick is the LUT value itself. A query with no live slot can skip its
+scoring (``fetch_live_rows``' count).
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -92,31 +104,38 @@ def fetch_live_rows(ids_ref, table_ref, tiles, rows, sem, slots):
 
     The rows of negative ids keep whatever they held: the caller masks
     their scores. A scalar pass lists each live slot and its id in
-    ``slots`` (SMEM: TB * R slot numbers, then TB * R ids), and the
-    copies, waits and extracts run over the list. The pass is unrolled
-    over a query's R ids, all loaded before the first store, so the
-    scalar core need not wait on each load in turn; the copy loops read
-    a listed id without first reading the slot that leads to it.
+    ``slots`` (SMEM: TB * R slot numbers, then TB * R ids, then each
+    query's live count), and the copies, waits and extracts run over the
+    list. The pass is unrolled over a query's R ids, all loaded before
+    the first store, so the scalar core need not wait on each load in
+    turn; the copy loops read a listed id without first reading the slot
+    that leads to it.
+
+    Returns ``live``: b -> whether query b of the block has a live slot.
     """
     tb, r = ids_ref.shape
     n_all = tb * r
 
     def listed(b, n):
         ids = [ids_ref[b, j] for j in range(r)]
+        n0 = n
         for j, i in enumerate(ids):
             slots[n] = b * r + j      # n <= b * r + j: kept if i is live
             slots[n_all + n] = i
             n = n + (i >= 0).astype(jnp.int32)
+        slots[2 * n_all + b] = n - n0
         return n
 
     n = jax.lax.fori_loop(0, tb, listed, jnp.int32(0))
     _fetch(n, lambda k: slots[k], lambda k: slots[n_all + k],
            table_ref, tiles, rows, sem)
+    return lambda b: slots[2 * n_all + b] > 0
 
 
 def slot_scratch(r: int):
-    """Scratch for ``fetch_live_rows``'s list of TB x r slots and ids."""
-    return pltpu.SMEM((2 * TB * r,), jnp.int32)
+    """Scratch for ``fetch_live_rows``'s list of TB x r slots and ids and
+    the TB live counts."""
+    return pltpu.SMEM((2 * TB * r + TB,), jnp.int32)
 
 
 def _fetch(n, slot, row_id, table_ref, tiles, rows, sem):
@@ -157,29 +176,57 @@ def l2_scores(rows, q):
     return jnp.sum(diff * diff, axis=-1)
 
 
-def lut_scores(rows, lut_ref, per_m, per_m_t):
-    """(TB*R, W) int32 codes vs (TB, M, C) LUTs -> (TB, R) ADC distances.
+def centroid_major(lut: jax.Array) -> jax.Array:
+    """A (Q, M, C) ADC table in the kernels' (Q, C, M) layout.
 
-    Candidate t's M picks (a one-hot select over C, summed: exact) land in
-    row t of ``per_m``; the transpose puts subspaces on sublanes, so the
+    One transpose, made once per search before its first hop: inside the
+    hop loop XLA would copy the whole table on every hop.
+    """
+    return jnp.swapaxes(lut, 1, 2)
+
+
+def lut_scores(rows, lut_ref, per_m, per_m_t, live=None):
+    """(TB*R, W) int32 codes vs (TB, C, M) LUTs -> (TB, R) ADC distances.
+
+    Query b's R code rows are one (R, M) block; for each centroid c its
+    picks take row c of the query's table where the code is c (a select,
+    so each pick is the LUT value). Rows of centroids load a sublane tile
+    at a time. ``live`` (b -> bool), if given, skips the queries with
+    nothing to score: their ``per_m`` rows keep stale values, which the
+    caller masks. The transpose then puts subspaces on sublanes, so the
     left-to-right sum over M is M-1 adds of (1, TB*R) rows.
     """
-    tb, m, c = lut_ref.shape
-    n = rows.shape[0]
+    tb, c, m = lut_ref.shape
+    r = rows.shape[0] // tb
 
-    def pick(t, carry):
-        code = rows[pl.ds(t, 1), :m].reshape(m, 1)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (m, c), 1)
-        sel = jnp.where(lane == code, lut_ref[t // (n // tb)], 0.0)
-        per_m[pl.ds(t, 1), :] = jnp.sum(sel, axis=1).reshape(1, m)
-        return carry
+    def score(b):
+        codes = rows[pl.ds(b * r, r), :m]                     # (R, M)
 
-    jax.lax.fori_loop(0, n, pick, 0)
+        def pick(first, size, picked):
+            table = lut_ref[b, pl.ds(first, size), :]         # (size, M)
+            for i in range(size):
+                picked = jnp.where(codes == first + i, table[i:i + 1, :],
+                                   picked)
+            return picked
+
+        picked = jax.lax.fori_loop(
+            0, c // TILE,
+            lambda t, p: pick(pl.multiple_of(t * TILE, TILE), TILE, p),
+            jnp.zeros((r, m), jnp.float32))
+        if c % TILE:
+            picked = pick(c - c % TILE, c % TILE, picked)
+        per_m[pl.ds(b * r, r), :] = picked
+
+    for b in range(tb):
+        if live is None:
+            score(b)
+        else:
+            pl.when(live(b))(functools.partial(score, b))
     per_m_t[...] = per_m[...].T
     acc = jax.lax.fori_loop(
         1, m, lambda mm, a: a + per_m_t[pl.ds(mm, 1), :],
         per_m_t[pl.ds(0, 1), :])
-    return acc.T.reshape(tb, n // tb)
+    return acc.T.reshape(tb, r)
 
 
 def compiler_params(*semantics):

@@ -290,28 +290,49 @@ _HOP_LIVENESS = {
     "block_dead": lambda sel, nbrs: (sel.at[:8].set(-1), nbrs),
     "empty_row": lambda sel, nbrs: (sel, nbrs.at[sel[1]].set(-1)),
     "all_live": lambda sel, nbrs: (jnp.abs(sel), jnp.abs(nbrs)),
+    # rows -1-padded past an out-degree of 0..R beside the dead lanes: a
+    # step mixes dead lanes, empty rows and partly live ones
+    "padded_rows": lambda sel, nbrs: (sel, jnp.where(
+        jnp.arange(nbrs.shape[1])[None, :]
+        < (jnp.arange(nbrs.shape[0]) % (nbrs.shape[1] + 1))[:, None],
+        nbrs, -1)),
 }
+
+# quantized hops as (dist_backend, M, C): M off the 128-lane tile (4, 48,
+# 300: PQ300's width), int8 at d'=600, and a C that is not 256
+_HOP_DISTS = {"f32": ("f32", 0, 0), "pq": ("pq", 4, 16),
+              "pq-m48": ("pq", 48, 256), "pq-m300": ("pq", 300, 256),
+              "int8": ("int8", 600, 256)}
+
+
+def _lut_codes(key, n, m, c):
+    """(n, m) uint8 codes in 0..c-1 with both ends in every row."""
+    codes = jax.random.randint(key, (n, m), 0, c)
+    return codes.at[:, 0].set(0).at[:, -1].set(c - 1).astype(jnp.uint8)
 
 
 @pytest.mark.parametrize("liveness", sorted(_HOP_LIVENESS))
-@pytest.mark.parametrize("dist_backend", ["f32", "pq"])
+@pytest.mark.parametrize("dist_backend", sorted(_HOP_DISTS))
 def test_beam_hop_pallas_bitexact_vs_ref(dist_backend, liveness):
     """One fused hop: the Pallas kernel (interpret) reproduces the jnp ref
     bit-for-bit — ids, distances, visited marks AND work counters — for
-    every pattern of dead lanes (sel < 0) and -1 neighbour slots."""
+    every pattern of dead lanes (sel < 0) and -1 neighbour slots, and for
+    PQ and int8 tables of several widths. The kernel takes the ADC table
+    centroid-major; the ref takes it (Q, M, C)."""
     from repro.kernels.beam_hop import beam_hop_pallas, beam_hop_ref
+    from repro.kernels.row_gather import centroid_major
 
     sel, nbrs, pi, pd, pv, q, db = _hop_inputs(3)
     sel, nbrs = _HOP_LIVENESS[liveness](sel, nbrs)
-    if dist_backend == "pq":
-        m, c = 4, 16
-        table = jax.random.randint(jax.random.PRNGKey(11),
-                                   (db.shape[0], m), 0, c).astype(jnp.uint8)
+    dist_backend, m, c = _HOP_DISTS[dist_backend]
+    q_kernel = q
+    if dist_backend != "f32":
+        db = _lut_codes(jax.random.PRNGKey(11), db.shape[0], m, c)
         q = jax.random.uniform(jax.random.PRNGKey(12), (q.shape[0], m, c))
-        db = table
+        q_kernel = centroid_major(q)
     ref = beam_hop_ref(sel, nbrs, pi, pd, pv, q, db,
                        dist_backend=dist_backend)
-    out = beam_hop_pallas(sel, nbrs, pi, pd, pv, q, db,
+    out = beam_hop_pallas(sel, nbrs, pi, pd, pv, q_kernel, db,
                           dist_backend=dist_backend, interpret=True)
     for r_, o_ in zip(ref, out):
         np.testing.assert_array_equal(np.asarray(r_), np.asarray(o_))

@@ -19,6 +19,7 @@ from repro.core.quant import (
 from repro.kernels.lut_dist import lut_dist
 from repro.kernels.lut_dist.lut_dist import lut_dist_pallas
 from repro.kernels.lut_dist.ref import lut_dist_ref
+from repro.kernels.row_gather import centroid_major
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -106,15 +107,22 @@ def test_lut_agrees_with_decoded_distance():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("m,c,r", [(4, 32, 9), (16, 256, 12), (1, 256, 5)])
+# M off the 128-lane tile up to PQ300's 300 and int8's 600 at d'=600; a C
+# that is not whole sublane tiles of 8 centroids
+@pytest.mark.parametrize("m,c,r", [(4, 32, 9), (16, 256, 12), (1, 256, 5),
+                                   (48, 256, 32), (300, 256, 32),
+                                   (600, 256, 32), (5, 12, 7)])
 def test_lut_dist_pallas_bit_exact(m, c, r):
+    """The kernel, over the centroid-major table, equals the ref; codes
+    take both ends of 0..c-1 in every row."""
     key = jax.random.PRNGKey(0)
     lut = jax.random.uniform(key, (7, m, c), dtype=jnp.float32) * 10
-    codes = jax.random.randint(jax.random.PRNGKey(1), (200, m), 0, c
-                               ).astype(jnp.uint8)
+    codes = jax.random.randint(jax.random.PRNGKey(1), (200, m), 0, c)
+    codes = codes.at[:, 0].set(0).at[:, -1].set(c - 1).astype(jnp.uint8)
     ids = jax.random.randint(jax.random.PRNGKey(2), (7, r), -1, 200)
     ref = np.asarray(lut_dist_ref(lut, codes, ids))
-    pal = np.asarray(lut_dist_pallas(lut, codes, ids, interpret=True))
+    pal = np.asarray(lut_dist_pallas(centroid_major(lut), codes, ids,
+                                     interpret=True))
     np.testing.assert_array_equal(ref, pal)
     # padding convention: negative ids come back +inf in both
     assert np.isinf(ref[np.asarray(ids) < 0]).all()
@@ -126,7 +134,8 @@ def test_lut_dist_backend_dispatch():
     ids = jnp.zeros((2, 3), jnp.int32)
     np.testing.assert_array_equal(
         np.asarray(lut_dist(lut, codes, ids, backend="jnp")),
-        np.asarray(lut_dist(lut, codes, ids, backend="pallas")))
+        np.asarray(lut_dist(centroid_major(lut), codes, ids,
+                            backend="pallas")))
     with pytest.raises(ValueError, match="backend"):
         lut_dist(lut, codes, ids, backend="bogus")
 
@@ -142,7 +151,8 @@ if HAVE_HYPOTHESIS:
         ids = jax.random.randint(k3, (3, r), -1, 50)
         np.testing.assert_array_equal(
             np.asarray(lut_dist_ref(lut, codes, ids)),
-            np.asarray(lut_dist_pallas(lut, codes, ids, interpret=True)))
+            np.asarray(lut_dist_pallas(centroid_major(lut), codes, ids,
+                                       interpret=True)))
 
 
 # ---------------------------------------------------------------------------

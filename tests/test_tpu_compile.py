@@ -5,7 +5,8 @@ block shapes off the (8, 128) tiling, unaligned DMA windows, ops it does
 not lower. These tests hand the TPU compiler a described ``v5e:2x2``
 chip — no chip needed — and compile each kernel at the shapes the
 ann-laion deployment serves: d'=600 (PCA600), R=32 (NSG32), ef=64,
-1024-query batches, PQ300 codes over the ~270k rows AntiHub keeps of 300k.
+1024-query batches, PQ300 codes over the ~270k rows AntiHub keeps of 300k
+(their ADC table centroid-major, (Q, C, M), as the kernels take it).
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and it keeps it until it
@@ -38,13 +39,13 @@ CASES = {
     "beam_hop_pq": (
         functools.partial(beam_hop_pallas, dist_backend="pq",
                           interpret=False),
-        HOP + (((Q, M, C), f32), ((N, M), u8))),
+        HOP + (((Q, C, M), f32), ((N, M), u8))),
     "gather_dist": (
         functools.partial(gather_dist_pallas, interpret=False),
         (((Q, D), f32), ((N, D), f32), ((Q, R), i32))),
     "lut_dist": (
         functools.partial(lut_dist_pallas, interpret=False),
-        (((Q, M, C), f32), ((N, M), u8), ((Q, R), i32))),
+        (((Q, C, M), f32), ((N, M), u8), ((Q, R), i32))),
     "topk_merge": (
         functools.partial(topk_merge_pallas, k=MERGE_K, interpret=False),
         (((MERGE_ROWS, MERGE_WIDTH), i32), ((MERGE_ROWS, MERGE_WIDTH), f32),
