@@ -48,7 +48,7 @@ import numpy as np
 
 from benchmarks.common import DIM, N_QUERIES, print_table, save, \
     save_bench_json
-from repro.core.beam_search import beam_search
+from repro.core.beam_search import beam_search, dot_gather_dist
 from repro.core.build import build_knn, knn_graph_recall
 from repro.core.flat import FlatIndex, recall_at_k
 from repro.core.nsg import build_nsg
@@ -64,7 +64,7 @@ SLOW_N = int(os.environ.get("BENCH_BUILD_SLOW_N", 0))
 def _graph_recall10(data, graph, queries, true_i):
     entry = jnp.full((queries.shape[0],), graph.medoid, jnp.int32)
     _, ids, _ = beam_search(queries, data, graph.neighbors, entry,
-                            ef=64, k=10)
+                            ef=64, k=10, gather_dist=dot_gather_dist)
     return float(recall_at_k(ids, true_i))
 
 

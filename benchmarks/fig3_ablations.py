@@ -17,7 +17,7 @@ import numpy as np
 
 from benchmarks.common import K, dataset, measure_qps, print_table, save
 from repro.core import IndexParams, TunedGraphIndex, recall_at_k
-from repro.core.beam_search import beam_search
+from repro.core.beam_search import beam_search, dot_gather_dist
 
 BASE = IndexParams(pca_dim=10**9, antihub_keep=1.0, ep_clusters=1,
                    ef_search=64, graph_degree=24, build_knn_k=24,
@@ -71,7 +71,8 @@ def run():
         q_p = vanilla.project(queries)
         _, _, hops = beam_search(q_p, vanilla.base,
                                  vanilla.graph.neighbors,
-                                 eps.select(q_p), ef=64, k=K)
+                                 eps.select(q_p), ef=64, k=K,
+                                 gather_dist=dot_gather_dist)
         rows_c.append([kc, round(r, 4), f"{qps:.1f}",
                        f"x{qps / qps0:.2f}", float(np.mean(hops))])
     print_table("Fig.3c entry points",

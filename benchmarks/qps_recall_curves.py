@@ -44,7 +44,7 @@ QUANT_SWEEPS = [
 ]
 
 # Adaptive-termination sweep (``stage="adaptive_term"`` in BENCH_qps.json):
-# the pinned NSG24,EP32 ef-sweep rerun with patience/compaction against the
+# the pinned NSG24,EP32 ef-sweep rerun with patience against the
 # patience=None baseline at each ef. CI gates on >= 1.3x fewer total hops
 # at a recall delta >= -0.005 for at least one point.
 ADAPTIVE_SPEC = "NSG24,EP32"
@@ -53,12 +53,11 @@ ADAPTIVE_EF_VALUES = (16, 32, 64, 128)
 # conservative point that clears the CI gate (>= 1.3x fewer hops within
 # 0.5pt recall) at both the committed 20k scale and the 1500-point smoke.
 ADAPTIVE_PATIENCE = (8, 24)
-ADAPTIVE_COMPACT_EVERY = 8
 
 
 def adaptive_term_points(data, queries, true_i):
     """Straggler-control sweep at the pinned spec: one baseline point plus
-    one adaptive (patience, compaction) point per patience value, per ef.
+    one adaptive point per patience value, per ef.
 
     ``total_hops`` counts hop-loop iterations the batch actually executed —
     useful hops plus the lock-stepped no-op hops converged lanes rode
@@ -79,7 +78,7 @@ def adaptive_term_points(data, queries, true_i):
                                queries, repeats=3)
         points.append({
             "stage": "adaptive_term", "spec": ADAPTIVE_SPEC, "ef": ef,
-            "patience": 0, "eps": 0.0, "compact_every": 0,
+            "patience": 0, "eps": 0.0,
             "recall": round(base_rec, 4), "qps": round(base_qps, 1),
             "total_hops": base_total, "useful_hops": bs["hops"],
             "wasted_hops": bs["wasted_hops"],
@@ -87,8 +86,7 @@ def adaptive_term_points(data, queries, true_i):
             "p99_hops": round(bs["p99_hops"], 2),
         })
         for patience in ADAPTIVE_PATIENCE:
-            params = SearchParams(ef_search=ef, patience=patience,
-                                  compact_every=ADAPTIVE_COMPACT_EVERY)
+            params = SearchParams(ef_search=ef, patience=patience)
             _, i = idx.search(queries, k, params)
             rec = float(recall_at_k(i, true_i))
             s = idx.search_stats()
@@ -98,7 +96,6 @@ def adaptive_term_points(data, queries, true_i):
             points.append({
                 "stage": "adaptive_term", "spec": ADAPTIVE_SPEC, "ef": ef,
                 "patience": patience, "eps": 0.0,
-                "compact_every": ADAPTIVE_COMPACT_EVERY,
                 "recall": round(rec, 4), "qps": round(qps, 1),
                 "total_hops": total, "useful_hops": s["hops"],
                 "wasted_hops": s["wasted_hops"],
@@ -108,7 +105,6 @@ def adaptive_term_points(data, queries, true_i):
                 "hop_reduction_vs_baseline":
                     round(base_total / max(total, 1), 3),
                 "recall_delta": round(rec - base_rec, 4),
-                "compaction_shapes": idx.last_compaction_shapes,
             })
     return points
 
@@ -328,13 +324,12 @@ def run():
                      f"{p['qps']:.1f}",
                      f"spill {p['spilled_bytes_per_hop']}B/hop"])
 
-    # adaptive termination + compaction vs the patience=None baseline at
+    # adaptive termination vs the patience=None baseline at
     # the pinned sweep (carries the >= 1.3x total-hop gate CI asserts on)
     at = adaptive_term_points(data, queries, ti)
     points.extend(at)
     for p in at:
-        tag = (f"Adapt{p['patience']}c{p['compact_every']}"
-               if p["patience"] else "baseline")
+        tag = f"Adapt{p['patience']}" if p["patience"] else "baseline"
         extra = (f"{p['hop_reduction_vs_baseline']}x fewer hops, "
                  f"recall {p['recall_delta']:+.4f}"
                  if p["patience"] else f"{p['total_hops']} total hops")

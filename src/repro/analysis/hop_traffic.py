@@ -129,10 +129,10 @@ def traversal_savings_report(stats: dict, ef: int, r: int, dim: int,
     ``stats`` is a ``TunedGraphIndex.search_stats()`` dict. ``hops`` hops
     did real work; ``wasted_hops`` are lock-stepped no-op hops the batch
     executed for lanes that had already converged — every one of them moves
-    the full per-hop byte bill for zero pool change. Compaction shrinks the
-    wasted count by re-packing survivors into smaller batches; adaptive
-    termination (patience/eps) shrinks the useful count by stopping lanes
-    before full-pool convergence. Pass the ``patience=None`` run's stats as
+    the full per-hop byte bill for zero pool change. Adaptive termination
+    (patience/eps) shrinks the useful count by stopping lanes before
+    full-pool convergence, and the wasted count when the batch's last lane
+    stops sooner. Pass the ``patience=None`` run's stats as
     ``baseline_stats`` to get the cross-run reduction ratios the ISSUE
     gate (>= 1.3x fewer total hops) is checked against.
     """

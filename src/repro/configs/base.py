@@ -168,7 +168,6 @@ class ANNConfig:
     hop_backend: str = "auto"        # staged | fused | auto (beam hop)
     patience: int = 0                # adaptive-termination hops (0 = off)
     eps: float = 0.0                 # top-k progress threshold for patience
-    compact_every: int = 0           # compaction slice length (0 = off)
     dtype: str = "float32"
 
 

@@ -1,7 +1,7 @@
 """Faithful host-side batching (paper Algorithms 1 & 2).
 
-On TPU, `vmap` gives every query its own entry point natively (see
-beam_search), so the grouping trick is unnecessary there. These reference
+On TPU, the batch-major beam_search gives every query its own entry point
+natively, so the grouping trick is unnecessary there. These reference
 implementations reproduce the paper's CPU/Faiss-style execution so the
 Algorithm-1-vs-2 comparison (their batching contribution) can be benchmarked:
 Algorithm 1 searches one query at a time; Algorithm 2 groups the batch by
@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.core.beam_search import beam_search
+from repro.core.beam_search import beam_search, dot_gather_dist
 
 
 def search_naive(index, queries, k: int):
@@ -24,7 +24,8 @@ def search_naive(index, queries, k: int):
     for qi in range(q.shape[0]):
         d, i, _ = beam_search(
             q[qi: qi + 1], index.base, index.graph.neighbors,
-            eps[qi: qi + 1], ef=max(index.params.ef_search, k), k=k)
+            eps[qi: qi + 1], ef=max(index.params.ef_search, k), k=k,
+            gather_dist=dot_gather_dist)
         out_d[qi] = np.asarray(d[0])
         kept = np.asarray(index.kept_idx)
         ii = np.asarray(i[0])
@@ -45,7 +46,8 @@ def search_grouped(index, queries, k: int):
         d, i, _ = beam_search(                     # paper's L7 (batched)
             batch, index.base, index.graph.neighbors,
             np.full((len(sel),), ep, np.int32),
-            ef=max(index.params.ef_search, k), k=k)
+            ef=max(index.params.ef_search, k), k=k,
+            gather_dist=dot_gather_dist)
         out_d[sel] = np.asarray(d)
         ii = np.asarray(i)
         out_i[sel] = np.where(ii >= 0, kept[np.maximum(ii, 0)], -1)

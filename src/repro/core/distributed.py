@@ -47,12 +47,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.beam_search import beam_search
+from repro.core.beam_search import beam_search, dot_gather_dist
 from repro.core.build.shardlocal import derive_local
 from repro.core.build.stream import HostOffloadStore
 from repro.core.distances import l2_topk
 from repro.core.index_api import build_index
-from repro.core.pipeline import IndexParams, TunedGraphIndex
+from repro.core.pipeline import (
+    IndexParams, TunedGraphIndex, drop_removed_knobs,
+)
 from repro.distributed.sharding import row_sharded_from_blocks
 
 
@@ -182,15 +184,16 @@ def _local_beam(q, base, nbrs, gids, cents, members, norms, *, ef: int,
     # route the query into row 0 of the wrong shard — mask them out
     cd = jnp.where((members >= 0)[None, :], cd, jnp.inf)
     entry = jnp.maximum(members[jnp.argmin(cd, axis=1)], 0)
-    gdist = None
+    gdist = dot_gather_dist
     if prenorm:
         # P8: |x|^2 precomputed at build; each expansion reads R norms
         # instead of squaring R*D gathered elements
-        def gdist(query, db, ids):
+        def one(query, db, ids):
             q32 = query.astype(jnp.float32)
             rows = db[ids].astype(jnp.float32)
             return jnp.maximum(jnp.sum(q32 * q32) + norms[ids]
                                - 2.0 * (rows @ q32), 0.0)
+        gdist = jax.vmap(one, in_axes=(0, None, 0))
     d, i, _ = beam_search(q, base, nbrs, entry, ef=ef, k=k,
                           max_iters=max_iters or 4 * ef, mode=mode,
                           gather_dist=gdist)
@@ -644,7 +647,6 @@ class ShardedFactoryIndex:
                  hop_backend: Optional[str] = None,
                  patience: Optional[int] = None,
                  eps: Optional[float] = None,
-                 compact_every: Optional[int] = None,
                  on_shard_error: str = "raise"):
         if on_shard_error not in ("raise", "skip"):
             raise ValueError(
@@ -659,7 +661,6 @@ class ShardedFactoryIndex:
         self.hop_backend = hop_backend         # per-shard beam-hop backend
         self.patience = patience               # per-shard adaptive patience
         self.eps = eps                         # per-shard progress threshold
-        self.compact_every = compact_every     # per-shard compaction slice
         self.on_shard_error = on_shard_error   # degraded-search default
         self.degraded_shards = 0               # failed shards, last search
         self.last_shard_errors: list = []      # (shard, exception) of same
@@ -694,8 +695,7 @@ class ShardedFactoryIndex:
                         rerank=self.rerank,
                         hop_backend=self.hop_backend,
                         patience=self.patience,
-                        eps=self.eps,
-                        compact_every=self.compact_every)
+                        eps=self.eps)
             for i in range(self.n_shards)
         ]
         self._structural_subs = self.subs
@@ -822,8 +822,7 @@ class ShardedFactoryIndex:
                     "rerank": self.rerank,
                     "hop_backend": self.hop_backend,
                     "patience": self.patience,
-                    "eps": self.eps,
-                    "compact_every": self.compact_every},
+                    "eps": self.eps},
                 "subs": subs_meta}
         return {"meta": meta, "arrays": arrays}
 
@@ -835,7 +834,7 @@ class ShardedFactoryIndex:
         meta, a = state["meta"], state["arrays"]
         idx = cls(meta["spec"], n_shards=meta["n_shards"],
                   on_shard_error=meta.get("on_shard_error", "raise"),
-                  **meta["overrides"])
+                  **drop_removed_knobs(meta["overrides"]))
         idx.input_dim = meta["input_dim"]
         idx.offsets = np.asarray(a["offsets"])
         if "pca_mean" in a:

@@ -274,6 +274,5 @@ class HNSWIndex:
         q = jnp.asarray(queries, jnp.float32)
         entries = self.entry_points(q)
         d, i, _ = beam_search(q, self._db, self._nbr0, entries,
-                              ef=max(ef, k), k=k, mode=mode,
-                              layout="batched")
+                              ef=max(ef, k), k=k, mode=mode)
         return d, i

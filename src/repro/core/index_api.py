@@ -68,8 +68,6 @@ class SearchParams:
                            NSG/TunedGraph (kernels/beam_hop)
       * ``patience`` / ``eps`` — adaptive early termination (0 = stock
                            full-pool convergence): NSG/TunedGraph
-      * ``compact_every`` — active-query compaction slice length (0 = the
-                           plain batched driver): NSG/TunedGraph
     """
     ef_search: Optional[int] = None
     nprobe: Optional[int] = None
@@ -80,7 +78,6 @@ class SearchParams:
     hop_backend: Optional[str] = None
     patience: Optional[int] = None
     eps: Optional[float] = None
-    compact_every: Optional[int] = None
 
     def resolve(self, name: str, default):
         v = getattr(self, name)
@@ -93,8 +90,7 @@ class SearchParams:
 jax.tree_util.register_dataclass(
     SearchParams, data_fields=[],
     meta_fields=["ef_search", "nprobe", "mode", "chunk", "rerank",
-                 "dist_backend", "hop_backend", "patience", "eps",
-                 "compact_every"])
+                 "dist_backend", "hop_backend", "patience", "eps"])
 
 
 def param_or(params: Optional[SearchParams], name: str, default):
@@ -295,8 +291,7 @@ def build_index(spec: str, data: jax.Array, *,
                 rerank: Optional[int] = None,
                 hop_backend: Optional[str] = None,
                 patience: Optional[int] = None,
-                eps: Optional[float] = None,
-                compact_every: Optional[int] = None) -> Index:
+                eps: Optional[float] = None) -> Index:
     """Build + fit an index from a factory string (the one-call entry point).
 
     ``knn_backend`` overrides the build-time kNN-graph backend ("exact" |
@@ -307,9 +302,8 @@ def build_index(spec: str, data: jax.Array, *,
     "int8") and ``rerank`` override the quantized-traversal serving knobs
     (in-grammar: ``,PQ<m>x8`` / ``,SQ8`` / ``,Rerank<k>``); ``hop_backend``
     ("staged" | "fused" | "auto") the beam-hop fusion (in-grammar:
-    ``,HopStaged`` / ``,HopFused``); ``patience`` / ``eps`` /
-    ``compact_every`` the straggler-control knobs (in-grammar:
-    ``,Adapt<p>[c<n>]`` — patience=0 / compact_every=0 disable).
+    ``,HopStaged`` / ``,HopFused``); ``patience`` / ``eps`` adaptive early
+    termination (in-grammar: ``,Adapt<p>`` — patience=0 disables).
 
     >>> idx = build_index("PCA16,IVF64", data)
     >>> dists, ids = idx.search(queries, 10, SearchParams(nprobe=4))
@@ -321,8 +315,7 @@ def build_index(spec: str, data: jax.Array, *,
                                    ("rerank", rerank),
                                    ("hop_backend", hop_backend),
                                    ("patience", patience),
-                                   ("eps", eps),
-                                   ("compact_every", compact_every))
+                                   ("eps", eps))
                  if v is not None}
     if overrides:
         from dataclasses import replace as _replace
@@ -498,11 +491,11 @@ def _ensure_builtins():
         "NSG", r"^NSG(\d+)?(?:a(\d+(?:\.\d+)?))?$",
         "NSG[<degree>][a<alpha>][,AH<keep>][,EP<k>][,ND<K>]"
         "[,PQ<m>x8|,SQ8][,Rerank<k>][,HopFused|,HopStaged]"
-        "[,Adapt<patience>[c<compact_every>]]",
+        "[,Adapt<patience>]",
         examples=("NSG12", "NSG12,EP8", "NSG12,AH0.9,EP8",
                   "NSG12a1.2,ND16", "NSG12,PQ8x8,Rerank32",
                   "NSG12,EP8,SQ8,Rerank32", "NSG12,EP8,HopFused",
-                  "NSG12,EP8,Adapt8", "NSG12,EP8,Adapt8c16"))
+                  "NSG12,EP8,Adapt8", "NSG12,EP8,SQ8,Rerank32,Adapt8"))
     def _nsg(m, rest, dim):
         degree = int(m.group(1)) if m.group(1) else 32
         alpha = float(m.group(2)) if m.group(2) else 1.0
@@ -510,7 +503,7 @@ def _ensure_builtins():
         backend, knn_k = "auto", None
         dist_backend, pq_m, rerank = "f32", 0, 64
         hop_backend = "auto"
-        patience, compact_every = 0, 0
+        patience = 0
         for tok in rest:
             em = re.match(r"^EP(\d+)$", tok)
             ah = re.match(r"^AH(0\.\d+|1(?:\.0+)?)$", tok)
@@ -518,7 +511,7 @@ def _ensure_builtins():
             pq = re.match(r"^PQ(\d+)x8$", tok)
             rr = re.match(r"^Rerank(\d+)$", tok)
             hp = re.match(r"^Hop(Fused|Staged)$", tok)
-            ad = re.match(r"^Adapt(\d+)(?:c(\d+))?$", tok)
+            ad = re.match(r"^Adapt(\d+)$", tok)
             if em:
                 ep = int(em.group(1))
             elif ah:
@@ -542,13 +535,6 @@ def _ensure_builtins():
                     raise ValueError(
                         f"Adapt patience must be >= 1 in token {tok!r} "
                         f"(omit the token to disable adaptive termination)")
-                if ad.group(2):
-                    compact_every = int(ad.group(2))
-                    if compact_every < 1:
-                        raise ValueError(
-                            f"Adapt compact_every must be >= 1 in token "
-                            f"{tok!r} (omit the c<n> suffix to disable "
-                            f"compaction)")
             else:
                 break
             used += 1
@@ -558,8 +544,7 @@ def _ensure_builtins():
             build_knn_k=knn_k if knn_k is not None else degree,
             build_candidates=max(2 * degree, 48), knn_backend=backend,
             dist_backend=dist_backend, pq_m=pq_m, rerank=rerank,
-            hop_backend=hop_backend, patience=patience,
-            compact_every=compact_every)
+            hop_backend=hop_backend, patience=patience)
         return TunedGraphIndex(params), used
 
     # only flag success: a failure above must surface again on retry, not

@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.beam_search import beam_search
+from repro.core.beam_search import beam_search, dot_gather_dist
 from repro.core.build.finish import finish_nsg, resolve_finish_backend
 from repro.core.build.pools import nnd_candidate_pools
 from repro.core.build.prune import (
@@ -123,7 +123,7 @@ def _candidate_pools(data, knn_ids, medoid, n_candidates, chunk,
         entry = jnp.full((e - s,), medoid, jnp.int32)
         d_pool, i_pool, hops = beam_search(
             q, data, knn_ids, entry, ef=ef, k=ef, max_iters=2 * ef,
-            mode="while")
+            mode="while", gather_dist=dot_gather_dist)
         own = knn_ids[s:e]                                     # (b, k)
         own_d = pairwise_rows_sqdist(q, data, own)
         hops_parts.append(hops)        # summed host-side AFTER the loop:

@@ -20,7 +20,7 @@ import numpy as np
 
 from repro.configs.base import ANNConfig
 from repro.core import antihub as antihub_mod
-from repro.core.beam_search import beam_search, beam_search_compacted
+from repro.core.beam_search import beam_search
 from repro.core.build import build_knn, reprune_nsg, resolve_backend
 from repro.core.build.nn_descent import nn_descent
 from repro.core.entry_points import EntryPointSelector, fit_entry_points
@@ -91,15 +91,12 @@ class IndexParams:
     # "fused" runs kernels/beam_hop (one Pallas launch per hop — the
     # (Q, R) candidate block never touches HBM). "auto" = fused on TPU.
     hop_backend: str = "auto"
-    # Straggler control (core/beam_search adaptive termination +
-    # compaction). patience=0 keeps the stock full-pool-convergence rule
-    # bit-for-bit; patience=p also stops a lane after p consecutive hops
-    # without a top-k prefix improvement > eps. compact_every=0 serves the
-    # plain batched driver; >0 runs beam_search_compacted with that
-    # hop-slice length (bucket-snapped batch shrinking between slices).
+    # Straggler control (core/beam_search adaptive termination).
+    # patience=0 keeps the stock full-pool-convergence rule bit-for-bit;
+    # patience=p also stops a lane after p consecutive hops without a
+    # top-k prefix improvement > eps.
     patience: int = 0
     eps: float = 0.0
-    compact_every: int = 0
 
     @staticmethod
     def from_config(cfg: ANNConfig) -> "IndexParams":
@@ -117,8 +114,22 @@ class IndexParams:
             rerank=getattr(cfg, "rerank", 64),
             hop_backend=getattr(cfg, "hop_backend", "auto"),
             patience=getattr(cfg, "patience", 0),
-            eps=getattr(cfg, "eps", 0.0),
-            compact_every=getattr(cfg, "compact_every", 0))
+            eps=getattr(cfg, "eps", 0.0))
+
+
+def drop_removed_knobs(fields: dict) -> dict:
+    """Snapshot knob fields minus the knobs this version no longer has.
+
+    Active-query compaction is gone: its stored slice length restores as
+    before when 0 (off) or None; any other value could not be honoured.
+    """
+    fields = dict(fields)
+    stored = fields.pop("compact_every", None)
+    if stored:
+        raise ValueError(
+            f"snapshot sets compact_every={stored} (active-query "
+            f"compaction), a knob that was removed; rebuild the index")
+    return fields
 
 
 class TunedGraphIndex:
@@ -141,7 +152,6 @@ class TunedGraphIndex:
         self.codes: Optional[jax.Array] = None       # (N, M) uint8 db codes
         self.codec_backend: Optional[str] = None     # "pq" | "int8"
         self.last_search_stats = None                # BeamStats of last search
-        self.last_compaction_shapes = None           # per-slice batch sizes
 
     # -- build ------------------------------------------------------------
     def fit(self, data: jax.Array, key: Optional[jax.Array] = None, *,
@@ -316,8 +326,7 @@ class TunedGraphIndex:
                dist_backend: Optional[str] = None,
                hop_backend: Optional[str] = None,
                patience: Optional[int] = None,
-               eps: Optional[float] = None,
-               compact_every: Optional[int] = None):
+               eps: Optional[float] = None):
         """Returns (dists (Q,k) in projected space, original ids (Q,k)).
 
         ``params`` is a ``core.index_api.SearchParams``; explicit keywords
@@ -329,9 +338,7 @@ class TunedGraphIndex:
         ``rerank=0``. ``hop_backend`` ("staged" | "fused" | "auto") picks
         the per-hop execution (see ``IndexParams.hop_backend``).
         ``patience``/``eps`` enable adaptive early termination (0 = stock
-        convergence, bit-for-bit) and ``compact_every`` > 0 serves through
-        the compacted driver (``core.beam_search.beam_search_compacted``) —
-        its per-slice batch shapes land in ``last_compaction_shapes``.
+        convergence, bit-for-bit).
         Per-hop work counters of the latest call are kept on the index —
         read them via ``search_stats()``.
         """
@@ -349,8 +356,6 @@ class TunedGraphIndex:
                 patience = getattr(params, "patience", None)
             if eps is None:
                 eps = getattr(params, "eps", None)
-            if compact_every is None:
-                compact_every = getattr(params, "compact_every", None)
         ef = ef or self.params.ef_search
         mode = mode or "while"
         dist_backend = dist_backend or self.params.dist_backend
@@ -358,17 +363,14 @@ class TunedGraphIndex:
         hop_backend = hop_backend or self.params.hop_backend
         patience = patience if patience is not None else self.params.patience
         eps = eps if eps is not None else self.params.eps
-        compact_every = (compact_every if compact_every is not None
-                         else self.params.compact_every)
         with span("index.search"):
             with span("search.project"):
                 q = self.project(queries)
             with span("search.entries"):
                 entries = self.eps.select(q)
             with span("search.traverse"):
-                # batch-major layout: every hop is one (Q, R) gather_dist
-                # block (Pallas kernel on TPU) — exact-parity with the vmap
-                # layout.
+                # batch-major: every hop is one (Q, R) expansion block
+                # (the fused beam_hop kernel on TPU)
                 bs_kw = dict(ef=max(ef, k), mode=mode,
                              hop_backend=hop_backend,
                              patience=patience or None, eps=eps,
@@ -386,18 +388,9 @@ class TunedGraphIndex:
                         lut = self.codec.lut(q)
                     bs_kw.update(dist_backend=dist_backend, codes=self.codes,
                                  lut=lut)
-                self.last_compaction_shapes = None
-                if compact_every:
-                    shape_log: list = []
-                    d, i, stats = beam_search_compacted(
-                        q, self.base, self.graph.neighbors, entries, k=kb,
-                        compact_every=compact_every, shape_log=shape_log,
-                        **bs_kw)
-                    self.last_compaction_shapes = shape_log
-                else:
-                    d, i, stats = beam_search(
-                        q, self.base, self.graph.neighbors, entries, k=kb,
-                        layout="batched", **bs_kw)
+                d, i, stats = beam_search(
+                    q, self.base, self.graph.neighbors, entries, k=kb,
+                    **bs_kw)
                 if dist_backend != "f32":
                     if rerank > 0:
                         with span("search.rerank"):
@@ -421,8 +414,8 @@ class TunedGraphIndex:
         the tests compare these dicts across backends.
 
         Straggler accounting: ``wasted_hops`` — loop iterations lanes rode
-        after their own termination (what adaptive termination shrinks and
-        compaction cuts off at slice boundaries); ``active_fraction`` —
+        after their own termination (what adaptive termination shrinks);
+        ``active_fraction`` —
         hops / (hops + wasted_hops), the useful share of hop-block rows;
         ``mean_hops`` / ``p99_hops`` — the per-query hop distribution whose
         tail is the batch straggler cost.
@@ -520,7 +513,7 @@ class TunedGraphIndex:
     def from_state(cls, state: dict) -> "TunedGraphIndex":
         from repro.core.quant import Int8Codec, PQCodec
         meta, a = state["meta"], state["arrays"]
-        idx = cls(IndexParams(**meta["params"]))
+        idx = cls(IndexParams(**drop_removed_knobs(meta["params"])))
         idx.input_dim = meta["input_dim"]
         idx.build_seconds = float(meta.get("build_seconds", 0.0))
         idx.kept_idx = jnp.asarray(a["kept_idx"])

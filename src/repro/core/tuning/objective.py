@@ -260,7 +260,7 @@ class AnnObjective:
         ef = max(p.ef_search, self.k)
         kw = dict(ef=ef, dist_backend=p.dist_backend, rerank=p.rerank,
                   hop_backend=p.hop_backend, patience=p.patience,
-                  eps=p.eps, compact_every=p.compact_every)
+                  eps=p.eps)
         d, i = idx.search(self.queries, self.k, **kw)       # warmup+compile
         jax.block_until_ready(d)
         times = []

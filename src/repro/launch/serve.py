@@ -35,7 +35,7 @@ from repro.serve.serve_step import (
 
 # build-time knobs the CLI can override on the config's pipeline or a spec
 ANN_OVERRIDES = ("knn_backend", "finish_backend", "dist_backend", "rerank",
-                 "hop_backend", "patience", "eps", "compact_every")
+                 "hop_backend", "patience", "eps")
 
 
 def ann_corpus(cfg, n: int, n_queries: int, seed: int = 0):
@@ -257,10 +257,6 @@ def main():
     ap.add_argument("--eps", type=float, default=None,
                     help="minimum top-k distance improvement that counts as "
                          "progress for --patience (ann family only)")
-    ap.add_argument("--compact-every", type=int, default=None,
-                    help="re-pack surviving lanes into a smaller bucketed "
-                         "batch every N hops (ann family only); ,Adapt<p>c<n>"
-                         " in-grammar")
     ap.add_argument("--snapshot", default=None, metavar="DIR",
                     help="save a checksummed index snapshot to DIR after "
                          "the build (core.persist.save_index; ann family "

@@ -125,10 +125,6 @@ def main():
     ap.add_argument("--eps", type=float, default=None,
                     help="top-k improvement threshold that counts as "
                          "progress for --patience (squared-L2 units)")
-    ap.add_argument("--compact-every", type=int, default=None,
-                    help="active-query compaction slice length: gather "
-                         "surviving lanes into a smaller pow2 bucket every "
-                         "this many hops (0 = plain batched driver)")
     ap.add_argument("--offload", action="store_true",
                     help="with --shards (no --spec): force the host-offload "
                          "streamed tier even when the mesh has enough "
@@ -157,8 +153,7 @@ def main():
                                   rerank=args.rerank,
                                   hop_backend=args.hop_backend,
                                   patience=args.patience,
-                                  eps=args.eps,
-                                  compact_every=args.compact_every).fit(
+                                  eps=args.eps).fit(
             data, key=key)
         obj = ShardedRepruneObjective(idx, data, queries, k=10,
                                       recall_floor=args.recall_floor,
@@ -168,8 +163,7 @@ def main():
         index = args.spec
         if (args.dist_backend is not None or args.rerank is not None
                 or args.hop_backend is not None
-                or args.patience is not None or args.eps is not None
-                or args.compact_every is not None):
+                or args.patience is not None or args.eps is not None):
             from repro.core.index_api import build_index
             index = build_index(args.spec, data, key=key,
                                 knn_backend=args.knn_backend,
@@ -178,8 +172,7 @@ def main():
                                 rerank=args.rerank,
                                 hop_backend=args.hop_backend,
                                 patience=args.patience,
-                                eps=args.eps,
-                                compact_every=args.compact_every)
+                                eps=args.eps)
         obj = SearchParamsObjective(index, data, queries, k=10,
                                     recall_floor=args.recall_floor,
                                     qps_repeats=3, key=key)
@@ -246,8 +239,7 @@ def main():
                            else 64,
                            hop_backend=args.hop_backend or "auto",
                            patience=args.patience or 0,
-                           eps=args.eps or 0.0,
-                           compact_every=args.compact_every or 0)
+                           eps=args.eps or 0.0)
         obj = AnnObjective(data, queries, k=10, base_params=base,
                            recall_floor=args.recall_floor, qps_repeats=3)
         space = default_space(args.dim, args.n,

@@ -1,19 +1,18 @@
-"""Adaptive early termination + active-query compaction.
+"""Adaptive early termination.
 
 Covers the straggler-control layer end to end: ``patience=None`` bit-parity
-with the exact-convergence loop (the tentpole's safety contract), recall
-monotonicity in ``patience``, compaction's bit-identical results and
-bucket-snapped retrace-free shape log, knob plumbing through SearchParams /
-the factory grammar / the sharded wrapper / the tuning space, the
-parse-time PQ ``m | dim`` validation, the serve-queue latency stats, and
-the hop-traffic savings pricing.
+with the exact-convergence loop (its safety contract), recall monotonicity
+in ``patience``, knob plumbing through SearchParams / the factory grammar /
+the sharded wrapper / the tuning space, the parse-time PQ ``m | dim``
+validation, the serve-queue latency stats, and the hop-traffic savings
+pricing.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.beam_search import beam_search, beam_search_compacted
+from repro.core.beam_search import beam_search
 from repro.core.index_api import SearchParams, build_index
 
 
@@ -42,8 +41,7 @@ def test_patience_none_bit_parity(ann_data, small_nsg, dist_backend,
     bit-for-bit, and a patience that can never fire (>= max_iters) must be
     indistinguishable from it — ids, dists AND stats."""
     q, db, nbrs, entries, kw = _beam_case(ann_data, small_nsg, dist_backend)
-    base = dict(ef=24, k=10, layout="batched", hop_backend=hop_backend,
-                with_stats=True, **kw)
+    base = dict(ef=24, k=10, hop_backend=hop_backend, with_stats=True, **kw)
     d0, i0, s0 = beam_search(q, db, nbrs, entries, patience=None, **base)
     d1, i1, s1 = beam_search(q, db, nbrs, entries, patience=4 * 24, **base)
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
@@ -52,27 +50,12 @@ def test_patience_none_bit_parity(ann_data, small_nsg, dist_backend,
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_patience_none_matches_vmap_layout(ann_data, small_nsg):
-    """The guarded batched hop at patience=None still equals the per-query
-    vmap(while_loop) reference — the pre-existing layout-parity contract."""
-    q, db, nbrs, entries, _ = _beam_case(ann_data, small_nsg)
-    _, iv, _ = beam_search(q, db, nbrs, entries, ef=24, k=10, layout="vmap")
-    _, ib, _ = beam_search(q, db, nbrs, entries, ef=24, k=10,
-                           layout="batched")
-    np.testing.assert_array_equal(np.asarray(iv), np.asarray(ib))
-
-
 def test_patience_validation(ann_data, small_nsg):
     q, db, nbrs, entries, _ = _beam_case(ann_data, small_nsg)
     with pytest.raises(ValueError, match="patience"):
-        beam_search(q, db, nbrs, entries, ef=16, k=5, layout="batched",
-                    patience=0)
-    with pytest.raises(ValueError, match="patience"):
-        beam_search(q, db, nbrs, entries, ef=16, k=5, layout="vmap",
-                    patience=4)
+        beam_search(q, db, nbrs, entries, ef=16, k=5, patience=0)
     with pytest.raises(ValueError, match="eps"):
-        beam_search(q, db, nbrs, entries, ef=16, k=5, layout="batched",
-                    eps=-0.5)
+        beam_search(q, db, nbrs, entries, ef=16, k=5, eps=-0.5)
 
 
 def test_adaptive_reduces_hops(ann_data, small_nsg):
@@ -80,70 +63,15 @@ def test_adaptive_reduces_hops(ann_data, small_nsg):
     convergence on real data, and the per-lane early exit rides into the
     wasted-hop accounting."""
     q, db, nbrs, entries, _ = _beam_case(ann_data, small_nsg)
-    base = dict(ef=48, k=10, layout="batched", with_stats=True)
+    base = dict(ef=48, k=10, with_stats=True)
     _, _, s_full = beam_search(q, db, nbrs, entries, **base)
     _, _, s_adapt = beam_search(q, db, nbrs, entries, patience=4, **base)
     assert int(jnp.sum(s_adapt.hops)) < int(jnp.sum(s_full.hops))
 
 
-# ------------------------------------------------------------- compaction
-@pytest.mark.parametrize("dist_backend", ["f32", "pq"])
-def test_compaction_bit_parity(ann_data, small_nsg, dist_backend):
-    """Compaction only re-packs lanes (they never interact): ids, dists,
-    hops, gathered and dup_gathered are bit-identical to the uncompacted
-    batched run; only wasted_hops may shrink."""
-    q, db, nbrs, entries, kw = _beam_case(ann_data, small_nsg, dist_backend)
-    base = dict(ef=32, k=10, with_stats=True, patience=4, **kw)
-    d0, i0, s0 = beam_search(q, db, nbrs, entries, layout="batched", **base)
-    shape_log = []
-    d1, i1, s1 = beam_search_compacted(q, db, nbrs, entries,
-                                       compact_every=4, shape_log=shape_log,
-                                       **base)
-    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
-    np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
-    np.testing.assert_array_equal(np.asarray(s0.hops), np.asarray(s1.hops))
-    np.testing.assert_array_equal(np.asarray(s0.gathered),
-                                  np.asarray(s1.gathered))
-    np.testing.assert_array_equal(np.asarray(s0.dup_gathered),
-                                  np.asarray(s1.dup_gathered))
-    assert int(jnp.sum(s1.wasted_hops)) <= int(jnp.sum(s0.wasted_hops))
-    # shape log: bucket-snapped (pow2), non-increasing, starts >= Q
-    assert shape_log and shape_log[0] >= q.shape[0]
-    assert all(b & (b - 1) == 0 for b in shape_log)
-    assert all(a >= b for a, b in zip(shape_log, shape_log[1:]))
-
-
-def test_compaction_requires_while_mode(ann_data, small_nsg):
-    q, db, nbrs, entries, _ = _beam_case(ann_data, small_nsg)
-    with pytest.raises(ValueError, match="while"):
-        beam_search_compacted(q, db, nbrs, entries, ef=16, k=5,
-                              compact_every=4, mode="fori")
-    with pytest.raises(ValueError, match="compact_every"):
-        beam_search_compacted(q, db, nbrs, entries, ef=16, k=5,
-                              compact_every=0)
-
-
-def test_compaction_no_retrace(ann_data, small_nsg):
-    """Every slice shape comes from the pre-declared bucket set, so a
-    second search (even with a different live-lane trajectory via another
-    query subset) adds zero fresh traces of the slice function."""
-    from repro.core.beam_search import _hop_slice
-    q, db, nbrs, entries, _ = _beam_case(ann_data, small_nsg)
-    base = dict(ef=32, k=10, compact_every=4, patience=4)
-    shape_log = []
-    beam_search_compacted(q, db, nbrs, entries, shape_log=shape_log, **base)
-    traced = _hop_slice._cache_size()
-    beam_search_compacted(q, db, nbrs, entries, **base)
-    beam_search_compacted(q[:17], db, nbrs, entries[:17], **base)
-    assert _hop_slice._cache_size() == traced
-    # and the dispatched shapes never left the bucket set
-    from repro.serve.batching import pow2_buckets
-    assert set(shape_log) <= set(pow2_buckets(q.shape[0]))
-
-
 # --------------------------------------------------- SearchParams plumbing
 def test_search_params_no_retrace(small_nsg, ann_data):
-    """patience/eps/compact_every ride SearchParams as jit-static meta:
+    """patience/eps ride SearchParams as jit-static meta:
     repeats reuse the compiled beam, flips cost at most one compile."""
     idx = small_nsg
     q = ann_data["queries"][:8]
@@ -163,14 +91,11 @@ def test_pipeline_adaptive_search_and_stats(small_nsg, ann_data):
     q = ann_data["queries"][:16]
     d, i = idx.search(q, 10, ef=32)
     base = idx.search_stats()
-    assert idx.last_compaction_shapes is None
-    d2, i2 = idx.search(q, 10, ef=32, patience=4, compact_every=4)
+    d2, i2 = idx.search(q, 10, ef=32, patience=4)
     st = idx.search_stats()
     assert st["hops"] < base["hops"]
     assert 0 < st["active_fraction"] <= 1.0
     assert st["mean_hops"] > 0 and st["p99_hops"] >= st["mean_hops"]
-    shapes = idx.last_compaction_shapes
-    assert shapes and all(b & (b - 1) == 0 for b in shapes)
     # recall sanity: the adaptive result still overlaps the exact one
     overlap = np.mean([len(set(a) & set(b)) / 10
                        for a, b in zip(np.asarray(i), np.asarray(i2))])
@@ -192,34 +117,29 @@ def test_recall_monotone_in_patience(small_nsg, ann_data):
 def test_factory_adapt_token(ann_data):
     data = ann_data["data"][:600]
     idx = build_index("NSG12,EP8,Adapt8", data, key=jax.random.PRNGKey(0))
-    assert idx.params.patience == 8 and idx.params.compact_every == 0
-    idx2 = build_index("NSG12,EP8,Adapt8c16", data,
-                       key=jax.random.PRNGKey(0))
-    assert idx2.params.patience == 8 and idx2.params.compact_every == 16
-    d, i = idx2.search(ann_data["queries"][:8], 10)
+    assert idx.params.patience == 8
+    d, i = idx.search(ann_data["queries"][:8], 10)
     assert i.shape == (8, 10)
     with pytest.raises(ValueError, match="patience"):
         build_index("NSG12,Adapt0", data, key=jax.random.PRNGKey(0))
-    with pytest.raises(ValueError, match="compact_every"):
-        build_index("NSG12,Adapt4c0", data, key=jax.random.PRNGKey(0))
+    # the compaction suffix left the grammar with its knob
+    with pytest.raises(ValueError, match="Adapt8c16"):
+        build_index("NSG12,EP8,Adapt8c16", data, key=jax.random.PRNGKey(0))
 
 
 def test_build_index_adaptive_overrides(ann_data):
     data = ann_data["data"][:600]
     idx = build_index("NSG12,EP8", data, key=jax.random.PRNGKey(0),
-                      patience=5, eps=0.01, compact_every=8)
+                      patience=5, eps=0.01)
     assert idx.params.patience == 5
     assert idx.params.eps == pytest.approx(0.01)
-    assert idx.params.compact_every == 8
 
 
 def test_sharded_factory_threads_patience(ann_data):
     from repro.core.distributed import ShardedFactoryIndex
-    idx = ShardedFactoryIndex("NSG8,EP2", n_shards=2, patience=6,
-                              compact_every=4).fit(
+    idx = ShardedFactoryIndex("NSG8,EP2", n_shards=2, patience=6).fit(
         ann_data["data"][:400], key=jax.random.PRNGKey(0))
     assert all(s.params.patience == 6 for s in idx.subs)
-    assert all(s.params.compact_every == 4 for s in idx.subs)
     d, i = idx.search(ann_data["queries"][:4], 5)
     assert i.shape == (4, 5)
 
@@ -286,7 +206,7 @@ def test_traversal_savings_report(small_nsg, ann_data):
     q = ann_data["queries"][:16]
     idx.search(q, 10, ef=32)
     base = idx.search_stats()
-    idx.search(q, 10, ef=32, patience=4, compact_every=4)
+    idx.search(q, 10, ef=32, patience=4)
     adapt = idx.search_stats()
     r = idx.graph.neighbors.shape[1]
     rep = traversal_savings_report(adapt, 32, r, idx.base.shape[1],

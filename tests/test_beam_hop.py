@@ -1,7 +1,7 @@
 """Serving-knob plumbing + traffic accounting for the fused beam hop.
 
 Kernel-level bit-parity lives in tests/test_kernels.py; this module covers
-the layers above it: the backend resolvers (env overrides included), the
+the layers above it: the backend resolvers, the
 ``hop_backend`` knob's path through SearchParams / IndexParams / the
 factory grammar / the sharded wrapper, the per-hop work counters surfaced
 by ``TunedGraphIndex.search_stats()``, and the per-hop HBM traffic model
@@ -15,9 +15,7 @@ import pytest
 from repro.analysis.hop_traffic import (
     fused_hop_traffic, hop_traffic_report, staged_hop_traffic,
 )
-from repro.core.beam_search import (
-    beam_search, resolve_gather_backend, resolve_hop_backend,
-)
+from repro.core.beam_search import beam_search, resolve_hop_backend
 from repro.core.index_api import SearchParams, build_index
 
 
@@ -30,35 +28,6 @@ def test_resolve_hop_backend_values():
     assert resolve_hop_backend("auto") == expected
     with pytest.raises(ValueError, match="hop backend"):
         resolve_hop_backend("bogus")
-
-
-def test_resolve_hop_backend_env(monkeypatch):
-    monkeypatch.setenv("REPRO_HOP_BACKEND", "fused")
-    assert resolve_hop_backend(None) == "fused"
-    assert resolve_hop_backend("auto") == "fused"
-    assert resolve_hop_backend("staged") == "staged"     # explicit wins
-    monkeypatch.setenv("REPRO_HOP_BACKEND", "bogus")
-    with pytest.raises(ValueError, match="hop backend"):
-        resolve_hop_backend(None)
-    # empty string == unset (shell `REPRO_HOP_BACKEND= cmd` idiom)
-    monkeypatch.setenv("REPRO_HOP_BACKEND", "")
-    expected = "fused" if jax.default_backend() == "tpu" else "staged"
-    assert resolve_hop_backend(None) == expected
-
-
-def test_resolve_gather_backend_env(monkeypatch):
-    """Regression for the env-override contract: the var only steers the
-    default resolution, explicit arguments always win, empty means unset,
-    and invalid values raise instead of silently falling through."""
-    monkeypatch.setenv("REPRO_GATHER_BACKEND", "pallas")
-    assert resolve_gather_backend(None) == "pallas"
-    assert resolve_gather_backend("jnp") == "jnp"        # explicit wins
-    monkeypatch.setenv("REPRO_GATHER_BACKEND", "")
-    expected = "pallas" if jax.default_backend() == "tpu" else None
-    assert resolve_gather_backend(None) == expected
-    monkeypatch.setenv("REPRO_GATHER_BACKEND", "nope")
-    with pytest.raises(ValueError, match="gather backend"):
-        resolve_gather_backend(None)
 
 
 # ---------------------------------------------------- SearchParams plumbing

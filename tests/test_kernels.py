@@ -153,12 +153,12 @@ if HAVE_HYPOTHESIS:
 # ----------------------------------------------- integration with the core
 def test_gather_dist_matches_beam_default_gather():
     """kernels/gather_dist (both backends) is a drop-in for the batched
-    traversal's default expansion (vmapped _default_gather_dist)."""
-    from repro.core.beam_search import _default_gather_dist
+    traversal's default expansion (``dot_gather_dist``)."""
+    from repro.core.beam_search import dot_gather_dist
     q = jax.random.normal(jax.random.PRNGKey(0), (6, 24))
     db = jax.random.normal(jax.random.PRNGKey(1), (80, 24))
     ids = jax.random.randint(jax.random.PRNGKey(2), (6, 12), 0, 80)
-    want = jax.vmap(_default_gather_dist, in_axes=(0, None, 0))(q, db, ids)
+    want = dot_gather_dist(q, db, ids)
     for backend in ("jnp", "pallas"):
         got = gather_dist(q, db, ids, backend=backend)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -172,7 +172,7 @@ def test_beam_batched_pallas_expansion_matches_ref(small_nsg, ann_data):
     idx = small_nsg
     q = idx.project(ann_data["queries"][:16])
     e = idx.eps.select(q)
-    kw = dict(ef=32, k=10, max_iters=96, mode="fori", layout="batched")
+    kw = dict(ef=32, k=10, max_iters=96, mode="fori")
     dj, ij, _ = beam_search(q, idx.base, idx.graph.neighbors, e,
                             gather_backend="jnp", **kw)
     dp, ip, _ = beam_search(q, idx.base, idx.graph.neighbors, e,
@@ -370,8 +370,7 @@ def test_fused_hop_bitexact_vs_staged(small_nsg, ann_data, dist_backend,
     idx = small_nsg
     q = idx.project(ann_data["queries"][:8])
     e = idx.eps.select(q)
-    kw = dict(ef=16, k=8, max_iters=48, mode=mode, layout="batched",
-              with_stats=True)
+    kw = dict(ef=16, k=8, max_iters=48, mode=mode, with_stats=True)
     if dist_backend != "f32":
         codec, codes = _hop_codec(idx, dist_backend)
         kw.update(dist_backend=dist_backend, codes=codes, lut=codec.lut(q))
@@ -388,8 +387,9 @@ def test_fused_hop_bitexact_vs_staged(small_nsg, ann_data, dist_backend,
 
 
 def test_fused_hop_matches_vmap_layout_diffsq(small_nsg, ann_data):
-    """The fused hop agrees with the per-query vmap layout when the latter
-    scores with the same diff-square arithmetic the kernel uses."""
+    """The fused hop agrees with the staged hop scoring through a custom
+    ``gather_dist``: a per-query diff-square, the arithmetic the kernel
+    uses, lifted over the batch by ``jax.vmap``."""
     from repro.core.beam_search import beam_search
 
     def _diffsq(query, db, ids):
@@ -401,27 +401,23 @@ def test_fused_hop_matches_vmap_layout_diffsq(small_nsg, ann_data):
     q = idx.project(ann_data["queries"][:8])
     e = idx.eps.select(q)
     kw = dict(ef=16, k=8, max_iters=48, mode="fori")
+    diffsq_b = jax.vmap(_diffsq, in_axes=(0, None, 0))
     dv, iv, _ = beam_search(q, idx.base, idx.graph.neighbors, e,
-                            layout="vmap", gather_dist=_diffsq, **kw)
+                            hop_backend="staged", gather_dist=diffsq_b, **kw)
     df, if_, _ = beam_search(q, idx.base, idx.graph.neighbors, e,
-                             layout="batched", hop_backend="fused",
-                             gather_backend="jnp", **kw)
+                             hop_backend="fused", gather_backend="jnp", **kw)
     np.testing.assert_array_equal(np.asarray(iv), np.asarray(if_))
     np.testing.assert_array_equal(np.asarray(dv), np.asarray(df))
 
 
-def test_fused_rejects_custom_gather_and_vmap_layout(small_nsg, ann_data):
+def test_fused_rejects_custom_gather(small_nsg, ann_data):
     from repro.core.beam_search import beam_search
     idx = small_nsg
     q = idx.project(ann_data["queries"][:4])
     e = idx.eps.select(q)
     kw = dict(ef=16, k=8, max_iters=16, mode="fori")
-    with pytest.raises(ValueError, match="vmap layout is always staged"):
-        beam_search(q, idx.base, idx.graph.neighbors, e, layout="vmap",
-                    hop_backend="fused", **kw)
     with pytest.raises(ValueError, match="custom gather_dist"):
-        beam_search(q, idx.base, idx.graph.neighbors, e, layout="batched",
-                    hop_backend="fused",
+        beam_search(q, idx.base, idx.graph.neighbors, e, hop_backend="fused",
                     gather_dist=lambda a, b, c: jnp.zeros(()), **kw)
 
 
@@ -443,7 +439,7 @@ if HAVE_HYPOTHESIS:
         _, ti = FlatIndex(data).search(q, 10)
         e = idx.eps.select(q)
         kw = dict(ef=max(ef, 10), k=10, max_iters=96, mode="while",
-                  layout="batched", gather_backend="jnp")
+                  gather_backend="jnp")
         _, i_st, _ = beam_search(q, idx.base, idx.graph.neighbors, e,
                                  hop_backend="staged", **kw)
         _, i_fu, _ = beam_search(q, idx.base, idx.graph.neighbors, e,

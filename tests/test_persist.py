@@ -115,6 +115,36 @@ def test_state_dict_is_host_serializable(persist_data):
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
 
 
+def test_restore_manifest_with_removed_compaction_knob(persist_data):
+    """Snapshots written while ``compact_every`` (active-query compaction)
+    still existed carry it in two manifests: the graph's ``IndexParams``
+    and the sharded wrapper's overrides. A stored 0 (off) restores to the
+    same search; a non-zero one is refused by name."""
+    import copy
+
+    from repro.core.distributed import ShardedFactoryIndex
+    data, queries = persist_data
+    idx = ShardedFactoryIndex("NSG12,EP4", n_shards=2)
+    idx.fit(data, key=jax.random.PRNGKey(0))
+    state = index_state(idx)
+    old = copy.deepcopy(state)
+    old["meta"]["overrides"]["compact_every"] = 0
+    for sub in old["meta"]["subs"]:
+        sub["meta"]["params"]["compact_every"] = 0
+    d0, i0 = idx.search(queries, 10)
+    d1, i1 = index_from_state(old).search(queries, 10)
+    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+    np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
+    for where in ("overrides", "params"):
+        bad = copy.deepcopy(old)
+        if where == "overrides":
+            bad["meta"]["overrides"]["compact_every"] = 8
+        else:
+            bad["meta"]["subs"][0]["meta"]["params"]["compact_every"] = 8
+        with pytest.raises(ValueError, match="compact_every"):
+            index_from_state(bad)
+
+
 # ---------------------------------------------------------------------------
 # validate_index: loaded indexes pass; corrupted structures name the
 # violated invariant

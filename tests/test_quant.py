@@ -166,12 +166,9 @@ def test_beam_search_quantized_requires_batched_and_codes(small_db):
     idx = build_index("NSG12,EP4", data, key=jax.random.PRNGKey(0))
     q = queries[:4]
     entries = idx.eps.select(q)
-    with pytest.raises(ValueError, match="batched"):
-        beam_search(q, idx.base, idx.graph.neighbors, entries, ef=16, k=5,
-                    layout="vmap", dist_backend="pq")
     with pytest.raises(ValueError, match="codes"):
         beam_search(q, idx.base, idx.graph.neighbors, entries, ef=16, k=5,
-                    layout="batched", dist_backend="pq")
+                    dist_backend="pq")
 
 
 @pytest.mark.slow
@@ -183,7 +180,7 @@ def test_quantized_beam_matches_adc_ranking(small_db):
     q = idx.project(queries[:8])
     lut = idx.codec.lut(q)
     d, i, _ = beam_search(q, idx.base, idx.graph.neighbors,
-                          idx.eps.select(q), ef=32, k=10, layout="batched",
+                          idx.eps.select(q), ef=32, k=10,
                           dist_backend="pq", codes=idx.codes, lut=lut)
     again = lut_dist_ref(lut, idx.codes, i)
     valid = np.asarray(i) >= 0
